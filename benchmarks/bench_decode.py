@@ -850,6 +850,9 @@ def run_speculative(quick: bool = True, smoke: bool = False):
 
 def main():
     import argparse
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes, 1 iter (CI import-and-run check)")

@@ -281,6 +281,9 @@ def run(quick: bool = True):
 
 def main():
     import argparse
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized pass (default)")

@@ -256,6 +256,9 @@ def run_telemetry(quick: bool = True, trace_path=None):
 
 def main():
     import argparse
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized pass: 3 models, 4 policy families")

@@ -34,6 +34,8 @@ def main() -> None:
                     help="comma-separated module subset")
     args, _ = ap.parse_known_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

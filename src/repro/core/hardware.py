@@ -13,6 +13,9 @@ import dataclasses
 @dataclasses.dataclass(frozen=True)
 class Hardware:
     name: str = "tpu-v5e"
+    # what jax reports as ``device_kind`` for this chip; a device not
+    # listed here is not described by these constants
+    device_kinds: tuple = ("TPU v5 lite", "TPU v5e")
     peak_flops: float = 197e12          # bf16 FLOP/s per chip
     hbm_bw: float = 819e9               # bytes/s per chip
     ici_bw: float = 50e9                # bytes/s per link

@@ -9,14 +9,17 @@ the new chunk is computed, dropping continuation cost from O(L²/chunk)
 to O(chunk)) and speculative-decoding verification (the k draft tokens
 are the chunk; their logits score the draft in one dispatch).
 
-TPU design mirrors the paged decode kernel: grid ``(segments, kv_heads,
-max_pages + 1)`` with the page dim innermost. Iterations ``j <
-max_pages`` stream history pages HBM→VMEM with the same block-table
-index map — past-history lookups clamp onto the last live page so
-revisit-elision never DMAs dead pages; the final iteration ``j ==
-max_pages`` attends the chunk's own rows under a local causal mask and
-finalizes the online softmax. Block tables, history lengths, and segment
-lengths all ride in via scalar prefetch so the index maps can page.
+TPU design mirrors the paged decode kernel: the pool is head-major
+``(KV, P, page_size, D)`` and the wrapper lays the chunk's queries and K/V
+out head-major too, so every block's last two dims are a (rows, D) tile
+Mosaic can lower. Grid ``(segments, kv_heads, max_pages + 1)`` with the
+page dim innermost. Iterations ``j < max_pages`` stream history pages
+HBM→VMEM with the same block-table index map — past-history lookups clamp
+onto the last live page so revisit-elision never DMAs dead pages; the
+final iteration ``j == max_pages`` attends the chunk's own rows under a
+local causal mask and finalizes the online softmax. Block tables, history
+lengths, and segment lengths all ride in via scalar prefetch so the index
+maps can page.
 
 Rows r >= the segment's length are unspecified (padding); callers slice
 the valid region. History length 0 (a fresh sequence) is fine — the
@@ -68,9 +71,9 @@ def _chunk_kernel(tbl_ref, hist_ref, slen_ref, q_ref, kh_ref, vh_ref,
 
     @pl.when(jnp.logical_and(j < n_pages, j * page_size < hist))
     def _history():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # (chunk*rep, D)
-        k = kh_ref[0, :, 0, :].astype(jnp.float32)      # (page_size, D)
-        v = vh_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)              # (chunk*rep, D)
+        k = kh_ref[...].astype(jnp.float32)             # (page_size, D)
+        v = vh_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = (j * page_size
@@ -83,9 +86,9 @@ def _chunk_kernel(tbl_ref, hist_ref, slen_ref, q_ref, kh_ref, vh_ref,
 
     @pl.when(j == n_pages)
     def _local():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # (chunk*rep, D)
-        k = kc_ref[0, :, 0, :].astype(jnp.float32)      # (chunk, D)
-        v = vc_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)              # (chunk*rep, D)
+        k = kc_ref[...].astype(jnp.float32)             # (chunk, D)
+        v = vc_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kcol = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -94,17 +97,16 @@ def _chunk_kernel(tbl_ref, hist_ref, slen_ref, q_ref, kh_ref, vh_ref,
             mask = jnp.logical_and(mask, qrow - kcol < window)
         s = jnp.where(mask, s, NEG_INF)
         _online(s, v)
-        o_ref[0, :, 0, :] = (acc_scr[...]
-                             / jnp.maximum(l_scr[...], 1e-30)
-                             ).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...]
+                      / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
                           block_tables, hist_lens, seg_lens, *,
                           window: int = 0, interpret: bool = False):
     """q/k_chunk/v_chunk: (S, R, H|KV, D) — R chunk rows per segment;
-    pages: (P, page_size, KV, D); block_tables: (S, max_pages) int32;
-    hist_lens/seg_lens: (S,) int32.
+    pages: (KV, P, page_size, D) head-major pools; block_tables:
+    (S, max_pages) int32; hist_lens/seg_lens: (S,) int32.
 
     Chunk row r of segment s sits at absolute position hist_lens[s] + r;
     it attends paged history [0, hist_lens[s]) plus chunk rows [0, r]
@@ -112,16 +114,18 @@ def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
     are unspecified padding. Table entries at or past the last live
     history page are never dereferenced (the index map clamps)."""
     s_, r, h, d = q.shape
-    _, page_size, kvh, _ = k_pages.shape
+    kvh, _, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     rep = h // kvh
     # flatten queries to (R*rep, D) rows per kv head: row p*rep + u is
     # chunk position p's u-th grouped query (matches the kernel's // rep)
     qg = q.reshape(s_, r, kvh, rep, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(s_, kvh, r * rep, d).transpose(0, 2, 1, 3)
+    qg = qg.reshape(s_, kvh, r * rep, d)
+    kc = k_chunk.transpose(0, 2, 1, 3)                  # (S, KV, R, D)
+    vc = v_chunk.transpose(0, 2, 1, 3)
     hist_lens = jnp.asarray(hist_lens, jnp.int32).reshape(-1)
     seg_lens = jnp.asarray(seg_lens, jnp.int32).reshape(-1)
-    block_tables = jnp.asarray(block_tables, jnp.int32)
+    block_tables = jnp.asarray(block_tables, jnp.int32).reshape(-1)
     kernel = functools.partial(
         _chunk_kernel, scale=1.0 / np.sqrt(d), page_size=page_size,
         n_pages=max_pages, rep=rep, chunk=r, window=window)
@@ -132,23 +136,23 @@ def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
         # the j == max_pages iteration reuses the last page harmlessly
         last = jnp.maximum(
             (hist_ref[s_i] + page_size - 1) // page_size, 1) - 1
-        page = tbl_ref[s_i, jnp.minimum(j, last)]
-        return (page, 0, g, 0)
+        page = tbl_ref[s_i * max_pages + jnp.minimum(j, last)]
+        return (g, page, 0, 0)
 
     def chunk_map(s_i, g, j, tbl_ref, hist_ref, slen_ref):
-        return (s_i, 0, g, 0)
+        return (s_i, g, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s_, kvh, max_pages + 1),
         in_specs=[
-            pl.BlockSpec((1, r * rep, 1, d), chunk_map),
-            pl.BlockSpec((1, page_size, 1, d), hist_map),
-            pl.BlockSpec((1, page_size, 1, d), hist_map),
-            pl.BlockSpec((1, r, 1, d), chunk_map),
-            pl.BlockSpec((1, r, 1, d), chunk_map),
+            pl.BlockSpec((None, None, r * rep, d), chunk_map),
+            pl.BlockSpec((None, None, page_size, d), hist_map),
+            pl.BlockSpec((None, None, page_size, d), hist_map),
+            pl.BlockSpec((None, None, r, d), chunk_map),
+            pl.BlockSpec((None, None, r, d), chunk_map),
         ],
-        out_specs=pl.BlockSpec((1, r * rep, 1, d), chunk_map),
+        out_specs=pl.BlockSpec((None, None, r * rep, d), chunk_map),
         scratch_shapes=[
             pltpu.VMEM((r * rep, 1), jnp.float32),
             pltpu.VMEM((r * rep, 1), jnp.float32),
@@ -158,9 +162,10 @@ def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, r * rep, kvh, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_, kvh, r * rep, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, hist_lens, seg_lens, qg, k_pages, v_pages,
-      k_chunk, v_chunk)
-    out = out.transpose(0, 2, 1, 3).reshape(s_, kvh, r, rep, d)
+    )(block_tables, hist_lens, seg_lens, qg, k_pages, v_pages, kc, vc)
+    out = out.reshape(s_, kvh, r, rep, d)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_, r, h, d)

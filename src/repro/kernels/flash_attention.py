@@ -13,6 +13,11 @@ chunked-jnp fallback cannot do with static shapes, and is worth ~2× on
 causal prefill.
 
 GQA is native: the kv-head block index is derived as ``h * KV // H``.
+
+The wrappers take the models' (B, S, H, D) activations and hand the kernel
+head-major (B, H, S, D) views, so each block is a (rows, D) tile: Mosaic
+lowers a block only when its last two dims are whole or (8, 128) aligned,
+and a head axis blocked at 1 in the second-to-last position is neither.
 """
 from __future__ import annotations
 
@@ -51,9 +56,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(should_run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -83,22 +88,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(j == last_j)
     def _finalize():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _segment_flash_kernel(seg_smem, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
-                          o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                          window: int, block_q: int, block_k: int, nk: int):
+def _segment_flash_kernel(qfirst_smem, klast_smem, q_ref, k_ref, v_ref,
+                          qseg_ref, kseg_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                          scale: float, window: int, block_q: int,
+                          block_k: int, nq: int, nk: int):
     """Packed-prefill flash body: same online softmax as ``_flash_kernel``
     plus a segment-equality mask, with fully cross-segment tiles skipped.
 
-    ``seg_smem`` is the scalar-prefetched (B, T) segment-id vector — the
+    ``qfirst_smem``/``klast_smem`` are the scalar-prefetched segment ids
+    of each q tile's FIRST token and each kv tile's LAST token — the
     segment *boundaries* read at tile granularity (the same trick the
     paged decode kernel plays with its block table): because ids are
-    non-decreasing along the packed row, a kv tile whose LAST id is below
-    the q tile's FIRST id lies entirely in earlier segments and is skipped
-    wholesale via ``pl.when``. ``qseg_ref``/``kseg_ref`` are the same ids
-    as VMEM tiles for the per-element mask inside surviving tiles."""
+    non-decreasing along the packed row, a kv tile whose last id is below
+    the q tile's first id lies entirely in earlier segments and is skipped
+    wholesale via ``pl.when``. ``qseg_ref`` (a (block_q, 1) column) and
+    ``kseg_ref`` (a (1, block_k) row) are the per-token ids as VMEM tiles
+    for the per-element mask inside surviving tiles."""
     bi = pl.program_id(0)
     i = pl.program_id(2)
     j = pl.program_id(3)
@@ -115,22 +123,20 @@ def _segment_flash_kernel(seg_smem, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
     # causal skip (future tiles) + segment skip (tiles wholly in earlier
     # segments: max kv-tile id < min q-tile id)
     should_run = k_start <= q_start + block_q - 1
-    should_run &= seg_smem[bi, k_start + block_k - 1] >= seg_smem[bi, q_start]
+    should_run &= klast_smem[bi * nk + j] >= qfirst_smem[bi * nq + i]
     if window:
         should_run &= (q_start - (k_start + block_k - 1)) < window
 
     @pl.when(should_run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        qseg = qseg_ref[0, :].reshape(block_q, 1)
-        kseg = kseg_ref[0, :].reshape(1, block_k)
-        mask = (qseg == kseg) & (qpos >= kpos)
+        mask = (qseg_ref[...] == kseg_ref[...]) & (qpos >= kpos)
         if window:
             mask &= (qpos - kpos) < window
         s = jnp.where(mask, s, NEG_INF)
@@ -151,7 +157,11 @@ def _segment_flash_kernel(seg_smem, q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
     @pl.when(j == last_j)
     def _finalize():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _head_major(x):
+    return x.transpose(0, 2, 1, 3)                 # (B,S,H,D) <-> (B,H,S,D)
 
 
 def segment_flash_attention(q, k, v, seg_ids, *, window: int = 0,
@@ -175,40 +185,50 @@ def segment_flash_attention(q, k, v, seg_ids, *, window: int = 0,
     seg = jnp.asarray(seg_ids, jnp.int32)
     seg = jnp.broadcast_to(seg.reshape(-1, t) if seg.ndim > 1
                            else seg[None, :], (b, t))
+    # tile boundaries for the scalar skip test, flattened 1-D (SMEM pads
+    # 2-D arrays), and the per-token ids as a column and a row for the mask
+    q_first = seg[:, ::block_q].reshape(-1)
+    k_last = seg[:, block_k - 1::block_k].reshape(-1)
 
     kernel = functools.partial(
         _segment_flash_kernel, scale=scale, window=window,
-        block_q=block_q, block_k=block_k, nk=nk)
+        block_q=block_q, block_k=block_k, nq=nq, nk=nk)
+
+    def kv_map(b_, h_, i, j, *_):
+        return (b_, h_ * kvh // h, j, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, h_, i, j, seg_ref: (b_, i, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b_, h_, i, j, seg_ref: (b_, j, h_ * kvh // h, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b_, h_, i, j, seg_ref: (b_, j, h_ * kvh // h, 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda b_, h_, i, j, seg_ref: (b_, i)),
-            pl.BlockSpec((1, block_k),
-                         lambda b_, h_, i, j, seg_ref: (b_, j)),
+            pl.BlockSpec((None, None, block_q, d),
+                         lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
+            pl.BlockSpec((None, None, block_k, d), kv_map),
+            pl.BlockSpec((None, None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_q, 1),
+                         lambda b_, h_, i, j, *_: (b_, i, 0)),
+            pl.BlockSpec((None, 1, block_k),
+                         lambda b_, h_, i, j, *_: (b_, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b_, h_, i, j, seg_ref: (b_, i, h_, 0)),
+        out_specs=pl.BlockSpec((None, None, block_q, d),
+                               lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-    )(seg, q, k, v, seg, seg)
+    )(q_first, k_last, _head_major(q), _head_major(k), _head_major(v),
+      seg[:, :, None], seg[:, None, :])
+    return _head_major(out)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -227,23 +247,29 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         _flash_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, nk=nk)
 
-    return pl.pallas_call(
+    def kv_map(b_, h_, i, j):
+        return (b_, h_ * kvh // h, j, 0)
+
+    out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda b_, h_, i, j: (b_, i, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b_, h_, i, j: (b_, j, h_ * kvh // h, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b_, h_, i, j: (b_, j, h_ * kvh // h, 0)),
+            pl.BlockSpec((None, None, block_q, d),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((None, None, block_k, d), kv_map),
+            pl.BlockSpec((None, None, block_k, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b_, h_, i, j: (b_, i, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, d),
+                               lambda b_, h_, i, j: (b_, h_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(_head_major(q), _head_major(k), _head_major(v))
+    return _head_major(out)

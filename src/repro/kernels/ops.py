@@ -8,6 +8,10 @@ Backends:
                     cost analysis of standard HLO is what the roofline reads).
 
 ``default_backend()`` picks ``pallas`` on real TPUs and ``jnp`` elsewhere.
+It is the one place that choice is made: the model layers
+(``repro.models.layers``) ask it before every kernel dispatch, so a test
+that replaces it with ``lambda: "interpret"`` runs the whole serving path
+through the kernel bodies on the CPU.
 """
 from __future__ import annotations
 
@@ -128,23 +132,32 @@ def ssd(x, dt, a, b, c, *, chunk: int = 128, backend: Optional[str] = None,
         return _ref.ssd_ref(x, dt, a, b, c, initial_state=initial_state)
     if backend == "jnp":
         return _ssd_chunked_jnp(x, dt, a, b, c, chunk, initial_state)
-    # pallas / interpret — pre-arrange to (B·H, NC, cl, ·)
-    assert initial_state is None, "pallas path starts from zero state"
-    bs, l, h, p = x.shape
+    # pallas / interpret — pad like the jnp path (dt=0 padding is exact),
+    # then pre-arrange to (B·H, NC, cl, ·) chunk blocks. The chunk is
+    # rounded up to a sublane multiple so short prompts keep aligned tiles.
+    bs, l0, h, p = x.shape
     n = b.shape[-1]
-    cl = min(chunk, l)
-    assert l % cl == 0, (l, cl)
-    nc = l // cl
+    cl = min(chunk, -(-l0 // 8) * 8)
+    pad = (-l0) % cl
     f32 = jnp.float32
-    xdt = (x.astype(f32) * dt.astype(f32)[..., None])      # (B,L,H,P)
-    adt = dt.astype(f32) * a.astype(f32)                   # (B,L,H)
+    xdt = x.astype(f32) * dt.astype(f32)[..., None]       # (B,L,H,P)
+    adt = dt.astype(f32) * a.astype(f32)                  # (B,L,H)
+    if pad:
+        xdt = jnp.pad(xdt, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        adt = jnp.pad(adt, ((0, 0), (0, pad), (0, 0)))
+        b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
+        c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
+    l = l0 + pad
+    nc = l // cl
     xdt = xdt.transpose(0, 2, 1, 3).reshape(bs * h, nc, cl, p)
-    adt = adt.transpose(0, 2, 1).reshape(bs * h, nc, cl)
-    bb = jnp.broadcast_to(b.astype(f32)[:, None], (bs, h, l, n)).reshape(bs * h, nc, cl, n)
-    cb = jnp.broadcast_to(c.astype(f32)[:, None], (bs, h, l, n)).reshape(bs * h, nc, cl, n)
-    y, state = _ssd.ssd_scan(xdt, adt, bb, cb,
-                             interpret=(backend == "interpret"))
-    y = y.reshape(bs, h, l, p).transpose(0, 2, 1, 3).astype(x.dtype)
+    a_cs = jnp.cumsum(adt.transpose(0, 2, 1).reshape(bs * h, nc, cl), -1)
+    s0 = (jnp.zeros((bs * h, n, p), f32) if initial_state is None
+          else initial_state.astype(f32).reshape(bs * h, n, p))
+    y, state = _ssd.ssd_scan(
+        xdt, a_cs, b.astype(f32).reshape(bs, nc, cl, n),
+        c.astype(f32).reshape(bs, nc, cl, n), s0,
+        interpret=(backend == "interpret"))
+    y = y.reshape(bs, h, l, p).transpose(0, 2, 1, 3)[:, :l0].astype(x.dtype)
     state = state.reshape(bs, h, n, p)
     return y, state
 

@@ -2,18 +2,24 @@
 
 The paged sibling of ``repro.kernels.decode_attention``: instead of one
 contiguous ``(B, C, KV, D)`` ring per sequence, K/V live in a shared pool of
-fixed-size pages ``(P, page_size, KV, D)`` and each sequence owns an ordered
-list of page ids — its *block table*, a ``(B, max_pages)`` int32 row (the
-vLLM PagedAttention layout; on TPU the same design ships as
-``ragged_paged_attention``). A sequence's logical cache position ``t`` lives
-at ``(block_tables[b, t // page_size], t % page_size)``.
+fixed-size pages and each sequence owns an ordered list of page ids — its
+*block table*, a ``(B, max_pages)`` int32 row (the vLLM PagedAttention
+layout). A sequence's logical cache position ``t`` lives at page
+``block_tables[b, t // page_size]``, row ``t % page_size``.
+
+The pool is HEAD-MAJOR, ``(KV, P, page_size, D)`` — the layout of the
+upstream ``jax.experimental.pallas.ops.tpu.paged_attention``. One page of
+one kv head is then a ``(page_size, D)`` tile whose last two dims are what
+the Mosaic lowering tiles (sublane x lane): a page-major pool would put the
+kv-head axis second-to-last, which Mosaic refuses to block at 1.
 
 TPU design mirrors the ragged decode kernel: grid ``(batch, kv_heads,
 max_pages)`` with the page dim innermost; the ``(rep, D)`` query group stays
 resident in VMEM while pages stream HBM→VMEM; online softmax in VMEM
-scratch. Both the block table AND the per-sequence lengths ride in via
-scalar prefetch (``pltpu.PrefetchScalarGridSpec``) so they are available to
-the *index maps*, which is where paging actually happens:
+scratch. Both the block table (flattened to 1-D, which SMEM stores without
+padding) AND the per-sequence lengths ride in via scalar prefetch
+(``pltpu.PrefetchScalarGridSpec``) so they are available to the *index
+maps*, which is where paging actually happens:
 
   * indirection — the K/V index map looks the j-th logical page up in the
     block table, so the kernel walks each row's pages in logical order no
@@ -58,9 +64,9 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(k_start < valid)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # (rep, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (page_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)             # (rep, D)
+        k = k_ref[...].astype(jnp.float32)             # (page_size, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -76,27 +82,28 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == n_pages - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...]
+                      / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            interpret: bool = False):
-    """q: (B, H, D); pages: (P, page_size, KV, D); block_tables:
-    (B, max_pages) int32 page ids; lengths: int32 scalar or (B,).
+    """q: (B, H, D); pages: (KV, P, page_size, D) head-major pools;
+    block_tables: (B, max_pages) int32 page ids; lengths: int32 scalar or
+    (B,).
 
     Returns (B, H, D). Rows with length 0 return zeros. Table entries at or
     past a row's last live page are never dereferenced (the index map clamps
     onto the last live page), so padding rows with any page id — by
     convention the null page 0 — is safe."""
     b, h, d = q.shape
-    _, page_size, kvh, _ = k_pages.shape
+    kvh, _, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     rep = h // kvh
     qg = q.reshape(b, kvh, rep, d)
     lengths = jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
-    block_tables = jnp.asarray(block_tables, jnp.int32)
+    block_tables = jnp.asarray(block_tables, jnp.int32).reshape(-1)
     kernel = functools.partial(_paged_kernel, scale=1.0 / np.sqrt(d),
                                page_size=page_size, n_pages=max_pages)
 
@@ -106,19 +113,19 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         # pages never leave HBM. The table lookup is the paging itself.
         last = jnp.maximum(
             (len_ref[b_] + page_size - 1) // page_size, 1) - 1
-        page = tbl_ref[b_, jnp.minimum(j, last)]
-        return (page, 0, g, 0)
+        page = tbl_ref[b_ * max_pages + jnp.minimum(j, last)]
+        return (g, page, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, d),
+            pl.BlockSpec((None, None, rep, d),
                          lambda b_, g, j, tbl_ref, len_ref: (b_, g, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
+            pl.BlockSpec((None, None, page_size, d), kv_map),
+            pl.BlockSpec((None, None, page_size, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
+        out_specs=pl.BlockSpec((None, None, rep, d),
                                lambda b_, g, j, tbl_ref, len_ref:
                                (b_, g, 0, 0)),
         scratch_shapes=[
@@ -131,6 +138,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, qg, k_pages, v_pages)
     return out.reshape(b, h, d)

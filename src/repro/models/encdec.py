@@ -170,11 +170,11 @@ def init_cache(cfg, batch: int, cache_len: int, dtype=None):
 def paged_cache_plan(cfg, batch: int, num_pages: int, page_size: int,
                      max_pages: int) -> dict:
     hd = cfg.resolved_head_dim
-    kv_shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, hd)
+    kv = L.paged_kv_plan(cfg, cfg.num_layers, num_pages, page_size)
     cross_shape = (cfg.num_layers, batch, cfg.encoder_seq, cfg.num_kv_heads, hd)
     return {
-        "k": ParamDef(kv_shape, L.paged_kv_cache_spec(cfg), "zeros"),
-        "v": ParamDef(kv_shape, L.paged_kv_cache_spec(cfg), "zeros"),
+        "k": kv,
+        "v": kv,
         "cross_k": ParamDef(cross_shape, L.kv_cache_spec(cfg), "zeros"),
         "cross_v": ParamDef(cross_shape, L.kv_cache_spec(cfg), "zeros"),
         "block_tables": ParamDef((batch, max_pages), None, "zeros"),

@@ -120,12 +120,9 @@ def init_cache(cfg, batch: int, cache_len: int, dtype=None):
 def paged_cache_plan(cfg, batch: int, num_pages: int, page_size: int,
                      max_pages: int) -> dict:
     base = ssm.cache_plan(cfg, batch, 0)
-    na = n_attn_blocks(cfg)
-    kv_shape = (na, num_pages, page_size, cfg.num_kv_heads,
-                cfg.resolved_head_dim)
-    spec = L.paged_kv_cache_spec(cfg)
-    base["attn_k"] = ParamDef(kv_shape, spec, "zeros")
-    base["attn_v"] = ParamDef(kv_shape, spec, "zeros")
+    kv = L.paged_kv_plan(cfg, n_attn_blocks(cfg), num_pages, page_size)
+    base["attn_k"] = kv
+    base["attn_v"] = kv
     base["block_tables"] = ParamDef((batch, max_pages), None, "zeros")
     return base
 

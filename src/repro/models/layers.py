@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
+
 
 # --------------------------------------------------------------------------
 # parameter plans
@@ -206,9 +208,10 @@ def big_attention(q, k, v, *, causal: bool, window: int = 0):
     """Dispatch: Pallas flash kernel on real TPUs; flash-with-custom-VJP
     (O(S) residuals, tile recomputation in backward) elsewhere."""
     s, sk = q.shape[1], k.shape[1]
-    if jax.default_backend() == "tpu" and s % 512 == 0 and sk % 512 == 0:
-        from repro.kernels.ops import flash_attention
-        return flash_attention(q, k, v, causal=causal, window=window)
+    backend = ops.default_backend()
+    if backend != "jnp" and s % 512 == 0 and sk % 512 == 0:
+        return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                   backend=backend)
     if max(s, sk) > 1024:
         from repro.kernels.flash_vjp import flash_attention_vjp
         cq = 512 if s % 512 == 0 else s
@@ -293,11 +296,12 @@ def packed_prefill_attention(q, k, v, seg_ids, positions, seg_starts,
     # half-step buckets (3·2^k) are 256-multiples, not 512-multiples —
     # drop to 256-wide tiles there so the kernel stays in play for every
     # packed bucket >= 512
-    if jax.default_backend() == "tpu" and t >= 512 and t % 256 == 0:
-        from repro.kernels.ops import segment_flash_attention
+    backend = ops.default_backend()
+    if backend != "jnp" and t >= 512 and t % 256 == 0:
         blk = 512 if t % 512 == 0 else 256
-        return segment_flash_attention(q, k, v, seg_ids, window=window,
-                                       block_q=blk, block_k=blk)
+        return ops.segment_flash_attention(q, k, v, seg_ids, window=window,
+                                           block_q=blk, block_k=blk,
+                                           backend=backend)
     qkv = jnp.concatenate([q[0], k[0], v[0]], axis=1)   # (T, H+2KV, D)
     rows = segments_to_rows(qkv, seg_starts, seg_lens, row_len)
     qr, kr, vr = rows[:, :, :h], rows[:, :, h:h + kvh], rows[:, :, h + kvh:]
@@ -338,15 +342,40 @@ def cache_row_update(buf, new, slot):
 
 
 def paged_cache_update(buf, new, pages, slots):
-    """Write ``new`` (B, 1, ...) into a paged pool ``buf``
-    (P, page_size, ...) at per-row physical page ``pages`` (B,) and
+    """Write ``new`` (B, 1, KV, D) into a head-major paged pool ``buf``
+    (KV, P, page_size, D) at per-row physical page ``pages`` (B,) and
     in-page offset ``slots`` (B,).
 
     Live rows own disjoint pages so the scatter rows never collide; vacant
     rows all target the reserved null page 0 at offset 0 — duplicate
     indices there are harmless because the null page is never read (see
     ``repro.serving.kv_cache``)."""
-    return buf.at[pages, slots].set(new[:, 0])
+    return buf.at[:, pages, slots].set(new[:, 0].swapaxes(0, 1))
+
+
+def gather_pages(pool, block_tables):
+    """The jnp fallback's view of a head-major pool (KV, P, page_size, D)
+    through (B, max_pages) block tables: each row's pages in logical order
+    as a contiguous (B, max_pages * page_size, KV, D) cache."""
+    kvh, _, page_size, d = pool.shape
+    b, max_pages = block_tables.shape
+    rows = pool[:, block_tables]               # (KV, B, max_pages, ps, D)
+    return rows.transpose(1, 2, 3, 0, 4).reshape(
+        b, max_pages * page_size, kvh, d)
+
+
+def _paged_backend(page_size: int) -> str:
+    """``ops.default_backend()`` for a paged cache. Where the Pallas
+    kernels run, they alone read the pages, and their (page_size, D) page
+    tile must be sublane aligned: a misaligned page is refused, not served
+    through the jnp gather, which reads every page a row's table spans and
+    would hide that the kernel never ran."""
+    backend = ops.default_backend()
+    if backend != "jnp" and page_size % 8:
+        raise ValueError(
+            f"page_size {page_size} is not a multiple of 8: the paged "
+            "Pallas kernels cannot tile it")
+    return backend
 
 
 def _masked_decode_attention(q, k_cache, v_cache, lengths):
@@ -374,10 +403,10 @@ def _masked_decode_attention(q, k_cache, v_cache, lengths):
 def paged_decode_attention(q, k_pages, v_pages, block_tables, valid_len):
     """Single-token attention over a block-table paged KV cache.
 
-    q: (B, H, D); k_pages/v_pages: (P, page_size, KV, D) shared physical
-    pools; block_tables: (B, max_pages) int32 page ids; valid_len: scalar
-    or per-sequence (B,) int32 lengths. Rows with length 0 (vacant slots,
-    table rows parked on the null page) return zeros.
+    q: (B, H, D); k_pages/v_pages: (KV, P, page_size, D) shared physical
+    head-major pools; block_tables: (B, max_pages) int32 page ids;
+    valid_len: scalar or per-sequence (B,) int32 lengths. Rows with length
+    0 (vacant slots, table rows parked on the null page) return zeros.
 
     On real TPUs this dispatches to the paged Pallas kernel
     (repro.kernels.paged_attention): the block table rides in via scalar
@@ -387,20 +416,17 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, valid_len):
     contiguous path — bit-identical to ring decode for equal contents.
     """
     b = q.shape[0]
-    _, page_size, kvh, d = k_pages.shape
-    max_pages = block_tables.shape[1]
     lengths = jnp.broadcast_to(
         jnp.asarray(valid_len, jnp.int32).reshape(-1), (b,))
-    # sublane-aligned pages dispatch to the kernel (the streamed page is a
-    # (page_size, d) tile — same shape family the ragged decode kernel
-    # streams); everything the repo builds uses page_size % 8 == 0
-    if jax.default_backend() == "tpu" and page_size % 8 == 0:
+    backend = _paged_backend(k_pages.shape[2])
+    if backend != "jnp":
         from repro.kernels.paged_attention import \
             paged_decode_attention as _pallas
-        return _pallas(q, k_pages, v_pages, block_tables, lengths)
+        return _pallas(q, k_pages, v_pages, block_tables, lengths,
+                       interpret=backend == "interpret")
     # jnp gather fallback: pages -> logical (B, C, KV, D) view
-    kc = k_pages[block_tables].reshape(b, max_pages * page_size, kvh, d)
-    vc = v_pages[block_tables].reshape(b, max_pages * page_size, kvh, d)
+    kc = gather_pages(k_pages, block_tables)
+    vc = gather_pages(v_pages, block_tables)
     return _masked_decode_attention(q, kc, vc, lengths)
 
 
@@ -434,7 +460,7 @@ def paged_chunk_attention(q_rows, k_pages, v_pages, k_rows, v_rows,
 
     q_rows/k_rows/v_rows: (S, R, H|KV, D) per-segment chunk rows (row r
     of segment s sits at absolute position hist_lens[s] + r);
-    k_pages/v_pages: (P, page_size, KV, D); block_tables: (S, max_pages)
+    k_pages/v_pages: (KV, P, page_size, D); block_tables: (S, max_pages)
     int32; hist_lens/seg_lens: (S,) int32. Rows r >= seg_lens[s] are
     padding (zeros in, garbage out — callers discard them).
 
@@ -444,19 +470,19 @@ def paged_chunk_attention(q_rows, k_pages, v_pages, k_rows, v_rows,
     absolute positions, and runs the exact masked-decode body per chunk
     position — bit-identical to the decode steps the chunk replaces."""
     s, r_len, h, d = q_rows.shape
-    _, page_size, kvh, _ = k_pages.shape
-    max_pages = block_tables.shape[1]
     hist = jnp.broadcast_to(
         jnp.asarray(hist_lens, jnp.int32).reshape(-1), (s,))
     slen = jnp.broadcast_to(
         jnp.asarray(seg_lens, jnp.int32).reshape(-1), (s,))
-    if jax.default_backend() == "tpu" and page_size % 8 == 0:
+    backend = _paged_backend(k_pages.shape[2])
+    if backend != "jnp":
         from repro.kernels.chunk_attention import \
             paged_chunk_attention as _pallas
         return _pallas(q_rows, k_pages, v_pages, k_rows, v_rows,
-                       block_tables, hist, slen)
-    kc = k_pages[block_tables].reshape(s, max_pages * page_size, kvh, d)
-    vc = v_pages[block_tables].reshape(s, max_pages * page_size, kvh, d)
+                       block_tables, hist, slen,
+                       interpret=backend == "interpret")
+    kc = gather_pages(k_pages, block_tables)
+    vc = gather_pages(v_pages, block_tables)
     pos = hist[:, None] + jnp.arange(r_len, dtype=jnp.int32)[None, :]
     sidx = jnp.arange(s)[:, None]
     kc = kc.at[sidx, pos].set(k_rows, mode="drop")
@@ -480,7 +506,7 @@ def decode_index(pos, cache, key):
     updated buffer; ``valid`` is the (B,) lengths vector."""
     if "block_tables" in cache:
         tables = cache["block_tables"]
-        page_size = cache[key].shape[2]
+        page_size = cache[key].shape[3]
         bidx = jnp.arange(pos.shape[0])
         # past-capacity clamp is belt-and-braces: the engine caps every
         # slot's token budget at its page capacity, so live rows never
@@ -553,9 +579,11 @@ def decode_attention(q, k_cache, v_cache, valid_len, *, window: int = 0,
     b, c, kvh, d = k_cache.shape
     lengths = jnp.broadcast_to(
         jnp.asarray(valid_len, jnp.int32).reshape(-1), (b,))
-    if jax.default_backend() == "tpu" and c % 128 == 0:
+    backend = ops.default_backend()
+    if backend != "jnp" and c % 128 == 0:
         from repro.kernels.decode_attention import decode_attention as _pallas
-        return _pallas(q, k_cache, v_cache, lengths)
+        return _pallas(q, k_cache, v_cache, lengths,
+                       interpret=backend == "interpret")
     return _masked_decode_attention(q, k_cache, v_cache, lengths)
 
 
@@ -659,14 +687,24 @@ def kv_cache_spec(cfg, tp: int = 16):
 
 
 def paged_kv_cache_spec(cfg, tp: int = 16):
-    """Sharding for a (layers, num_pages, page_size, kv_heads, head_dim)
+    """Sharding for a (layers, kv_heads, num_pages, page_size, head_dim)
     paged pool. KV heads shard when they divide the TP width (same local
     decode-attention argument as ``kv_cache_spec``); otherwise the *page*
     dim shards — pages are the paged analogue of the sequence dim, and the
     block table (host-replicated int32) stays tiny either way."""
     if cfg.num_kv_heads and cfg.num_kv_heads % tp == 0:
-        return ("stack", None, None, "kv_heads", None)
-    return ("stack", "kv_seq", None, None, None)
+        return ("stack", "kv_heads", None, None, None)
+    return ("stack", None, "kv_seq", None, None)
+
+
+def paged_kv_plan(cfg, n_layers: int, num_pages: int, page_size: int):
+    """The one paged K/V pool layout every family uses: HEAD-MAJOR
+    (layers, KV, P, page_size, D), so one page of one kv head is the
+    (page_size, D) tile the paged Pallas kernels stream (the layout of
+    the upstream TPU paged-attention kernel)."""
+    return ParamDef((n_layers, cfg.num_kv_heads, num_pages, page_size,
+                     cfg.resolved_head_dim), paged_kv_cache_spec(cfg),
+                    "zeros")
 
 
 def attn_qkv(p, cfg, x, positions):

@@ -129,12 +129,10 @@ def paged_cache_plan(cfg, batch: int, num_pages: int, page_size: int,
     """Block-table paged layout: K/V live in a shared (num_pages,
     page_size) pool; each row maps logical pages to physical via its
     ``block_tables`` row (see ``repro.serving.kv_cache``)."""
-    lcfg = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads,
-            cfg.resolved_head_dim)
-    spec = L.paged_kv_cache_spec(cfg)
+    kv = L.paged_kv_plan(cfg, cfg.num_layers, num_pages, page_size)
     return {
-        "k": L.ParamDef(lcfg, spec, "zeros"),
-        "v": L.ParamDef(lcfg, spec, "zeros"),
+        "k": kv,
+        "v": kv,
         "block_tables": L.ParamDef((batch, max_pages), None, "zeros"),
         "pos": L.ParamDef((batch,), None, "zeros"),
     }
@@ -250,7 +248,7 @@ def prefill_chunk(params, cfg, packed, cache, max_seg_len: int):
     row each segment's history lives in (padding = n_rows, clamped) —
     and ``hist_lens`` (S,) — tokens already resident per segment
     (padding = 0). ``cache`` is the engine's paged slot cache, READ
-    ONLY: (layers, P, page_size, KV, D) pools + (n_rows, max_pages)
+    ONLY: (layers, KV, P, page_size, D) pools + (n_rows, max_pages)
     ``block_tables``. Returns (per-segment last-position logits (S, V),
     per-token argmax (T,) int32, a packed cache {k/v: (layers, T, KV, D),
     pos: hist + seg_lens}) — the engine scatters the chunk's K/V into

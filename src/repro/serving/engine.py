@@ -2036,10 +2036,12 @@ def _make_write_slot_paged(paged_keys, page_size: int):
                 out[key] = jax.lax.dynamic_update_slice_in_dim(
                     b_leaf, table_row[None], slot, axis=0)
             elif key in paged_keys:
-                o = one[key]                     # (layers, 1, slot_len, ...)
+                o = one[key]                     # (layers, 1, slot_len, KV, D)
                 o = o[:, 0].reshape(
                     (o.shape[0], max_pages, page_size) + o.shape[3:])
-                out[key] = b_leaf.at[:, table_row].set(o.astype(b_leaf.dtype))
+                o = o.transpose(0, 3, 1, 2, 4)   # head-major page pool
+                out[key] = b_leaf.at[:, :, table_row].set(
+                    o.astype(b_leaf.dtype))
             else:
                 o_leaf = one[key].astype(b_leaf.dtype)
                 axis = 0 if b_leaf.ndim == 1 else 1
@@ -2064,13 +2066,18 @@ def _make_write_segments(paged_keys):
 
     def write(cache, last_tok, pcache, logits, dest0, dest1, seg_slots,
               table_rows):
+        paged = "block_tables" in cache
         out = {}
         for key, b_leaf in cache.items():
             if key == "block_tables":
                 out[key] = b_leaf.at[seg_slots].set(table_rows)
             elif key in paged_keys:
-                o = pcache[key].astype(b_leaf.dtype)      # (layers, T, ...)
-                out[key] = b_leaf.at[:, dest0, dest1].set(o)
+                o = pcache[key].astype(b_leaf.dtype)   # (layers, T, KV, D)
+                if paged:       # head-major pool (layers, KV, P, ps, D)
+                    out[key] = b_leaf.at[:, :, dest0, dest1].set(
+                        o.swapaxes(1, 2))
+                else:           # ring (layers, slots, C, KV, D)
+                    out[key] = b_leaf.at[:, dest0, dest1].set(o)
             else:
                 o = pcache[key].astype(b_leaf.dtype)      # (layers, S, ...)
                 axis = 0 if b_leaf.ndim == 1 else 1
@@ -2106,10 +2113,10 @@ def _make_copy_page(paged_keys):
     def copy(cache, src, dst):
         out = dict(cache)
         for key in sorted(paged_keys):
-            leaf = out[key]                   # (layers, pages, page_size, …)
-            page = jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=1)
+            leaf = out[key]            # (layers, KV, pages, page_size, D)
+            page = jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=2)
             out[key] = jax.lax.dynamic_update_slice_in_dim(
-                leaf, page, dst, axis=1)
+                leaf, page, dst, axis=2)
         return out
 
     return copy
@@ -2143,10 +2150,11 @@ def _clear_ring(pos, mask, slot):
     return pos.at[slot].set(0), mask.at[slot].set(False)
 
 
-def make_engine(cfg, *, seed: int = 0, cache_len: int = 256,
-                dtype=jnp.float32) -> InferenceEngine:
-    """Convenience constructor used by examples/tests (CPU scale)."""
+def make_engine(cfg, *, seed: int = 0, cache_len: int = 256
+                ) -> InferenceEngine:
+    """Engine over random weights from ``seed``, stored in ``cfg.dtype``
+    (bf16 at published widths, fp32 for ``cfg.reduced()``)."""
     from repro.models.registry import build_model
     api = build_model(cfg)
-    params = api.init(jax.random.PRNGKey(seed), dtype)
+    params = api.init(jax.random.PRNGKey(seed), jnp.dtype(cfg.dtype))
     return InferenceEngine(api, params, cache_len=cache_len)

@@ -884,7 +884,7 @@ def build_host(name: str, *, profile: Optional[ModelProfile] = None,
     if reduced:
         cfg = cfg.reduced()
     api = build_model(cfg)
-    params = api.init(jax.random.PRNGKey(seed))
+    params = api.init(jax.random.PRNGKey(seed), jnp.dtype(cfg.dtype))
     if paged and api.paged_keys and prompt_len >= cache_len:
         raise ValueError(
             f"{name}: prompt_len {prompt_len} leaves no decode room in a "
@@ -912,8 +912,10 @@ def build_pool(names: Sequence[str], *, request_rate: float = 500.0,
                lazy_kv: bool = False,
                planner_config: Optional[PlannerConfig] = None,
                prefix_cache: bool = False) -> EnginePool:
-    """Build an EnginePool over reduced real models and (by default) warm
-    every standby executable so the measured run compiles nothing.
+    """Build an EnginePool over real models — ``cfg.reduced()`` twins, or
+    with ``reduced=False`` the published widths with weights in the
+    config's dtype (bf16) — and (by default) warm every standby
+    executable so the measured run compiles nothing.
     ``slots`` / ``pages`` override slot count / usable page count per
     model name (the ROADMAP "per-model tuning" knobs — e.g. give a
     p50-lagging model more slots without re-sizing every host);

@@ -113,7 +113,7 @@ def test_ssd_jnp_matches_sequential_oracle(b, l, h, p, n, chunk):
                                atol=2e-3, rtol=2e-3)
 
 
-@pytest.mark.parametrize("b,l,h,p,n,chunk", SSD_SHAPES[:3])
+@pytest.mark.parametrize("b,l,h,p,n,chunk", SSD_SHAPES)
 def test_ssd_pallas_interpret_matches_oracle(b, l, h, p, n, chunk):
     ks = jax.random.split(KEY, 5)
     x = jax.random.normal(ks[0], (b, l, h, p))
@@ -123,6 +123,29 @@ def test_ssd_pallas_interpret_matches_oracle(b, l, h, p, n, chunk):
     cc = jax.random.normal(ks[4], (b, l, n))
     y_ref, s_ref = ref.ssd_ref(x, dt, a, bb, cc)
     y, s = ops.ssd(x, dt, a, bb, cc, chunk=chunk, backend="interpret")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               atol=2e-3, rtol=2e-3)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
+                               atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+@pytest.mark.parametrize("l", [5, 37])
+def test_ssd_ragged_length_with_initial_state(backend, l):
+    """Serving hands the scan prompts of any length and, for a continued
+    sequence, a carried-in state: both backends pad with dt = 0 (decay 1,
+    update 0 — exact) and start from that state."""
+    ks = jax.random.split(KEY, 6)
+    b, h, p, n = 2, 3, 16, 8
+    x = jax.random.normal(ks[0], (b, l, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h)))
+    a = -jnp.exp(0.5 * jax.random.normal(ks[2], (h,)))
+    bb = jax.random.normal(ks[3], (b, l, n))
+    cc = jax.random.normal(ks[4], (b, l, n))
+    s0 = jax.random.normal(ks[5], (b, h, n, p))
+    y_ref, s_ref = ref.ssd_ref(x, dt, a, bb, cc, initial_state=s0)
+    y, s = ops.ssd(x, dt, a, bb, cc, chunk=16, backend=backend,
+                   initial_state=s0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
@@ -237,13 +260,14 @@ def _mk_chunk_case(seed, s, r, h, kv, d, page_size, max_pages, hists, slens):
     q = rng.standard_normal((s, r, h, d), np.float32)
     kc = rng.standard_normal((s, r, kv, d), np.float32)
     vc = rng.standard_normal((s, r, kv, d), np.float32)
-    k_pages = rng.standard_normal((n_pages, page_size, kv, d), np.float32)
-    v_pages = rng.standard_normal((n_pages, page_size, kv, d), np.float32)
+    # head-major pools (kv, pages, page_size, d)
+    k_pages = rng.standard_normal((kv, n_pages, page_size, d), np.float32)
+    v_pages = rng.standard_normal((kv, n_pages, page_size, d), np.float32)
     perm = rng.permutation(n_pages - 1) + 1  # page 0 never used: catches
     tables = perm[:s * max_pages].reshape(s, max_pages)  # accidental zeros
     cap = max_pages * page_size
-    k_hist = k_pages[tables].reshape(s, cap, kv, d)
-    v_hist = v_pages[tables].reshape(s, cap, kv, d)
+    k_hist = k_pages[:, tables].transpose(1, 2, 3, 0, 4).reshape(s, cap, kv, d)
+    v_hist = v_pages[:, tables].transpose(1, 2, 3, 0, 4).reshape(s, cap, kv, d)
     return (jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
             jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(tables),
             jnp.asarray(hists, jnp.int32), jnp.asarray(slens, jnp.int32),
@@ -313,7 +337,7 @@ def test_chunk_attention_segment_isolation():
     base = np.asarray(paged_chunk_attention(q, kp, vp, kc, vc, tbl, hist,
                                             slen, interpret=True))
     victim_pages = np.asarray(tbl)[1]            # segment 1's pages
-    kp2 = jnp.asarray(np.asarray(kp)).at[jnp.asarray(victim_pages)].set(7.0)
+    kp2 = jnp.asarray(np.asarray(kp)).at[:, jnp.asarray(victim_pages)].set(7.0)
     out = np.asarray(paged_chunk_attention(q, kp2, vp, kc, vc, tbl, hist,
                                            slen, interpret=True))
     assert not np.allclose(base[1], out[1])      # victim did change

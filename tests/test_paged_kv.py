@@ -237,13 +237,18 @@ def _paged_setup(b, h, kv, d, ps, maxp, lengths, seed=0):
     n_phys = b * maxp + 1
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, h, d))
-    kp = jax.random.normal(ks[1], (n_phys, ps, kv, d))
-    vp = jax.random.normal(ks[2], (n_phys, ps, kv, d))
+    kp = jax.random.normal(ks[1], (kv, n_phys, ps, d))    # head-major pool
+    vp = jax.random.normal(ks[2], (kv, n_phys, ps, d))
     rng = np.random.default_rng(seed)
     tables = rng.permutation(np.arange(1, n_phys))[:b * maxp] \
         .reshape(b, maxp).astype(np.int32)
-    kc = jnp.asarray(np.asarray(kp)[tables].reshape(b, maxp * ps, kv, d))
-    vc = jnp.asarray(np.asarray(vp)[tables].reshape(b, maxp * ps, kv, d))
+
+    def logical(pool):
+        rows = np.asarray(pool)[:, tables]            # (kv, b, maxp, ps, d)
+        return jnp.asarray(rows.transpose(1, 2, 3, 0, 4)
+                           .reshape(b, maxp * ps, kv, d))
+
+    kc, vc = logical(kp), logical(vp)
     return q, kp, vp, jnp.asarray(tables), kc, vc
 
 
