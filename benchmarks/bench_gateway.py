@@ -20,13 +20,11 @@ wall pass may trace a bounded handful of first-seen prefill packings —
 host pacing decides how prefills pack — never the decode path).
 
 A wall-clock pass then re-serves the same trace with
-``AsyncGateway(wall_clock=True)`` and PR 7's ``StepTimers`` attached
-(SLOs relaxed — CPU-host ticks run an order of magnitude slower than
-the 1ms virtual tick, so real-time deadlines would reject the trace):
-streams must stay BIT-EXACT with the virtual-clock run, and the
-roofline report joins measured per-dispatch wall clock against the
-latency-model predictions (deviations are flagged, not fatal — on a CPU
-host essentially every row flags, which is the signal).
+``AsyncGateway(wall_clock=True)`` and a ``TraceRecorder`` attached (SLOs
+relaxed — CPU-host ticks run an order of magnitude slower than the 1ms
+virtual tick, so real-time deadlines would reject the trace): streams
+must stay BIT-EXACT with the virtual-clock run, and the ring must hold
+a valid trace with one ``dstack.tick`` span per executed tick.
 
 CLI: ``PYTHONPATH=src python benchmarks/bench_gateway.py [--quick|--full]
 [--json [PATH]]``; also wired into ``benchmarks/run.py`` as
@@ -106,7 +104,7 @@ def _wall_serve(cfg, eng, reqs, prompts, *, tiers, telemetry=None):
     """Wall-clock serve with SLOs relaxed: a CPU-host tick takes
     ~10-30ms real against the 1ms virtual tick, so real-time deadlines
     would reject nearly every request the virtual run admitted — the
-    wall pass validates pacing, timers and streams, not attainment."""
+    wall pass validates pacing, spans and streams, not attainment."""
     slos = [r.slo for r in reqs]
     for r in reqs:
         r.slo = 1e9
@@ -143,9 +141,9 @@ def _score(reqs, planner, gw):
 
 def run_with_results(quick: bool = True):
     """Serve the burst trace under both policies plus the wall-clock
-    pass; returns (rows, {policy: score}, roofline rows)."""
-    from repro.core.profiles import build_profile
-    from repro.serving.telemetry import Telemetry, roofline_report
+    pass; returns (rows, {policy: score})."""
+    from repro.serving.telemetry import (Telemetry, TraceRecorder,
+                                         validate_chrome_trace)
     from repro.serving.traffic import (TIER_WEIGHTS, burst_trace,
                                        offered_by, synth_prompts)
 
@@ -210,10 +208,10 @@ def run_with_results(quick: bool = True):
                  f"jain {fifo['tenant_jain']:.4f}->"
                  f"{tiered['tenant_jain']:.4f}"))
 
-    # wall-clock pass: same trace, host-paced ticks, StepTimers attached
-    # behind block-until-ready; streams must not move by a bit
-    # (deadlines relaxed inside _wall_serve — see its docstring)
-    tel = Telemetry()                     # timers only, no trace
+    # wall-clock pass: same trace, host-paced ticks, the trace ring
+    # attached; streams must not move by a bit (deadlines relaxed inside
+    # _wall_serve — see its docstring)
+    tel = Telemetry(trace=TraceRecorder())
     eng.attach_telemetry(tel)
     try:
         streams, planner, gw, wall_s = _wall_serve(
@@ -235,21 +233,19 @@ def run_with_results(quick: bool = True):
         f"wall-clock pass recompiled the decode path: {grown}"
     assert sum(grown.values()) <= 6, \
         f"wall-clock pass recompilation not shape-bounded: {grown}"
-    report = roofline_report(
-        tel.timers, {cfg.name: build_profile(MODEL, request_rate=1000.0)})
-    assert report, "wall-clock pass timed no dispatches"
-    flagged = sum(1 for r in report if r.flagged)
+    n_spans = validate_chrome_trace(tel.trace.to_chrome_trace())
+    ticks = sum(1 for ev in tel.trace.events if ev["name"] == "dstack.tick")
+    assert ticks == gw.server.ticks, "wall-clock pass traced no ticks"
     rows.append(("gateway/wall_clock/bit_exact", wall_s * 1e6,
                  f"{gw.server.ticks} ticks host-paced, streams identical "
                  f"to virtual"))
-    rows.append(("gateway/wall_clock/roofline_rows", 0.0,
-                 f"{len(report)} rows, {flagged} flagged at 4x tol "
-                 f"(CPU host vs TPU rooflines — deviations are the "
-                 f"signal)"))
+    rows.append(("gateway/wall_clock/spans", 0.0,
+                 f"{n_spans} spans in the trace ring, one dstack.tick per "
+                 f"tick"))
     rows.append(("gateway/recompilations", 0.0,
                  f"0 measured; wall pass traced "
                  f"{sum(grown.values())} first-seen prefill packings"))
-    return rows, scores, report
+    return rows, scores
 
 
 def run_scenarios(quick: bool = True):
@@ -275,7 +271,7 @@ def run_scenarios(quick: bool = True):
 
 def run(quick: bool = True):
     """``benchmarks/run.py`` entry point — CSV rows only."""
-    rows, _, _ = run_with_results(quick)
+    rows, _ = run_with_results(quick)
     return rows + run_scenarios(quick)
 
 
@@ -290,27 +286,21 @@ def main():
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--json", nargs="?", const="BENCH_gateway.json",
                     default=None, metavar="PATH", dest="json_out",
-                    help="write rows + per-policy scorecards + roofline "
-                         "report as dstack-bench-v1 JSON (default "
+                    help="write rows + per-policy scorecards as "
+                         "dstack-bench-v1 JSON (default "
                          "BENCH_gateway.json)")
     args = ap.parse_args()
     quick = not args.full
-    rows, scores, report = run_with_results(quick)
+    rows, scores = run_with_results(quick)
     rows += run_scenarios(quick)
     print("name,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}", flush=True)
-    print()
-    from repro.serving.telemetry import format_roofline
-    print("roofline validation (measured wall-clock vs latency_model)")
-    for line in format_roofline(report):
-        print(line)
     if args.json_out:
         payload = _common.bench_payload(
             "bench_gateway", rows,
             args={"quick": quick},
-            extra={"scores": scores,
-                   "roofline": [r.as_dict() for r in report]})
+            extra={"scores": scores})
         _common.write_json(args.json_out, payload)
         print(f"wrote {args.json_out} ({len(rows)} rows)")
 

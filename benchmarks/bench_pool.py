@@ -191,21 +191,17 @@ def run_with_results(quick: bool = True):
 
 def run_telemetry(quick: bool = True, trace_path=None):
     """The telemetry pass (always on under main()): serve the dstack
-    policy with the ``Telemetry`` plane attached — wall-clock step timers
-    behind block-until-ready on every dispatch — and join the measured
-    per-(model, chips, kind, bucket) latencies against the
-    ``core/latency_model`` rooflines (the ISSUE 7 roofline-validation
-    report). With ``trace_path`` set a ``TraceRecorder`` also runs and
-    the Perfetto-loadable Chrome trace is validated and written there.
-    Attaching telemetry must neither recompile nor change behavior
-    (asserted here via jit_cache_sizes; bit-identity is proved in
-    tests/test_telemetry.py). Returns (rows, roofline rows, Prometheus
+    policy with the ``Telemetry`` plane attached, its ``TraceRecorder``
+    ring recording the engines' spans and the requests' lifecycle
+    instants. The Chrome trace is validated, and written to
+    ``trace_path`` when set. Attaching telemetry must neither recompile
+    nor change behavior (asserted here via jit_cache_sizes; bit-identity
+    is proved in tests/test_telemetry.py). Returns (rows, Prometheus
     text, PoolResult)."""
     from repro.serving.controller import run_policy
     from repro.serving.pool import build_pool
     from repro.serving.telemetry import (MetricsRegistry, Telemetry,
                                          TraceRecorder, export_pool_result,
-                                         roofline_report,
                                          validate_chrome_trace)
 
     rate = 2000.0
@@ -214,8 +210,8 @@ def run_telemetry(quick: bool = True, trace_path=None):
     pool = build_pool(["qwen2-0.5b", "olmo-1b"], request_rate=rate,
                       base_slots=4, cache_len=32)
     jit_before = pool.jit_cache_sizes()
-    # attached AFTER warmup (like faults): timing covers warm executables
-    tel = Telemetry(trace=TraceRecorder() if trace_path else None)
+    # attached AFTER warmup (like faults): the ring sees warm serving
+    tel = Telemetry(trace=TraceRecorder())
     pool.attach_telemetry(tel)
     try:
         res = run_policy(pool, "dstack", rate=rate, duration=duration,
@@ -224,15 +220,14 @@ def run_telemetry(quick: bool = True, trace_path=None):
         pool.attach_telemetry(None)
     assert not res.truncated, "telemetry pass hit a controller backstop"
     assert pool.jit_cache_sizes() == jit_before, "telemetry recompiled"
-    report = roofline_report(tel.timers, pool.profiles)
-    assert report, "telemetry pass timed no dispatches"
-    flagged = sum(1 for r in report if r.flagged)
-    rows = [("pool/telemetry/dispatches_timed", (time.time() - t0) * 1e6,
-             f"{tel.timers.total_samples} wall samples over "
-             f"{len(tel.timers.samples)} (model,chips,kind,bucket) keys"),
-            ("pool/telemetry/roofline_rows", 0.0,
-             f"{len(report)} rows, {flagged} flagged at 4x tol "
-             f"(CPU host vs TPU rooflines — deviations are the signal)")]
+    obj = tel.trace.to_chrome_trace()
+    n_spans = validate_chrome_trace(obj)
+    dispatches = sum(1 for ev in tel.trace.events
+                     if ev.get("cat") == "dispatch")
+    assert dispatches, "telemetry pass traced no dispatches"
+    rows = [("pool/telemetry/dispatch_spans", (time.time() - t0) * 1e6,
+             f"{dispatches} dispatch spans over "
+             f"{len(tel.trace.tracks())} tracks")]
     # per-request streaming latency (TTFT/TBT), virtual time — the
     # figures end-to-end latency hides (satellite: RequestQueue TTFT)
     for n, m in sorted(res.per_model.items()):
@@ -246,12 +241,11 @@ def run_telemetry(quick: bool = True, trace_path=None):
     export_pool_result(reg, res)
     prom = reg.render()
     if trace_path:
-        obj = tel.trace.save(trace_path)
-        n_spans = validate_chrome_trace(obj)
+        tel.trace.save(trace_path)
         rows.append(("pool/telemetry/trace", 0.0,
                      f"{len(obj['traceEvents'])} events ({n_spans} spans, "
                      f"{len(tel.trace.tracks())} tracks) -> {trace_path}"))
-    return rows, report, prom, res
+    return rows, prom, res
 
 
 def main():
@@ -274,24 +268,18 @@ def main():
                          "trace_pool.json)")
     ap.add_argument("--json", nargs="?", const="BENCH_pool.json",
                     default=None, metavar="PATH", dest="json_out",
-                    help="write rows + roofline report + Prometheus "
-                         "snapshot as dstack-bench-v1 JSON (default "
-                         "BENCH_pool.json)")
+                    help="write rows + Prometheus snapshot as "
+                         "dstack-bench-v1 JSON (default BENCH_pool.json)")
     args = ap.parse_args()
     quick = not args.full
     rows, results = run_with_results(quick)
     if args.faults:
         rows += run_faults(quick)
-    trows, report, prom, _ = run_telemetry(quick, trace_path=args.trace)
+    trows, prom, _ = run_telemetry(quick, trace_path=args.trace)
     rows += trows
     print("name,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}", flush=True)
-    print()
-    from repro.serving.telemetry import format_roofline
-    print("roofline validation (measured wall-clock vs latency_model)")
-    for line in format_roofline(report):
-        print(line)
     print()
     print("policy           summary (virtual time; real jitted engines)")
     for res in results:
@@ -302,8 +290,7 @@ def main():
             "bench_pool", rows,
             args={"quick": quick, "faults": bool(args.faults),
                   "trace": bool(args.trace)},
-            extra={"roofline": [r.as_dict() for r in report],
-                   "prometheus": prom})
+            extra={"prometheus": prom})
         _common.write_json(args.json_out, payload)
         print(f"wrote {args.json_out} ({len(rows)} rows)")
 

@@ -52,7 +52,7 @@ def bench_payload(bench: str, rows, args=None, extra=None) -> dict:
     """Wrap a benchmark's ``(name, us_per_call, derived)`` rows in the
     shared schema. ``args`` records the CLI shape that produced the
     numbers (quick vs full runs are not comparable); ``extra`` carries
-    bench-specific structured sections (roofline report, Prometheus
+    bench-specific structured sections (scorecards, Prometheus
     snapshot, ...)."""
     return {
         "schema": BENCH_SCHEMA,

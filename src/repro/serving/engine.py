@@ -75,15 +75,18 @@ make that true on the host side:
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import layers as L
 from repro.models.registry import ModelAPI
 from repro.serving.faults import EngineFault, TransientFault
 from repro.serving.kv_cache import NULL_PAGE, OutOfPages, PagedKVCache
+from repro.serving.telemetry import Telemetry, span
 
 
 def _pow2_at_least(n: int) -> int:
@@ -163,6 +166,13 @@ class EngineStats:
     accepted_tokens: int = 0
     spec_rounds: int = 0
     rollbacks: int = 0
+    # blocking device->host reads (``InferenceEngine.host_sync``): each
+    # holds the host until the device has run everything before it
+    host_syncs: int = 0
+    # misses of the engine's jit dictionaries: each builds a new step
+    # executable, compiled (or loaded from the persistent cache) on its
+    # first call
+    executables_built: int = 0
 
 
 class InferenceEngine:
@@ -188,17 +198,18 @@ class InferenceEngine:
         self.fault_injector = None
         self.retry_limit = 2
         self.retry_backoff_s = 0.0
-        # telemetry plane (repro.serving.telemetry): when attached, each
-        # of execute()'s ≤3 dispatches is wall-clock timed behind a
-        # block_until_ready and traced as a sub-span. None = every
-        # instrumentation site is a single attribute check (no clock
-        # reads, no blocking, bit-identical behavior).
+        # telemetry plane (repro.serving.telemetry): the engine's spans
+        # are profiler annotations whether or not one is attached; an
+        # attached Telemetry with a TraceRecorder also records them, and
+        # the engine's instants, on this engine's track. Nothing blocks.
         self.telemetry = None
+        self._track = Telemetry.engine_track(self)
 
         if mesh is not None:
             from jax.sharding import NamedSharding
             pspecs = api.param_specs(mesh)
-            self._param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
+            self._param_sh = jax.tree.map(partial(NamedSharding, mesh),
+                                          pspecs)
         else:
             self._param_sh = None
 
@@ -206,13 +217,14 @@ class InferenceEngine:
         self._gen_jit: Dict[Any, Any] = {}
         donate = (2,) if donate_cache else ()
         self._donate_cache_argnums = donate
-        self._decode = jax.jit(
-            lambda p, tok, cache: api.decode_step(p, tok, cache),
-            donate_argnums=donate)
+
+        def dstack_decode_step(p, tok, cache):
+            return api.decode_step(p, tok, cache)
+        self._decode = jax.jit(dstack_decode_step, donate_argnums=donate)
         # one slot-step executable per sampling config (None = greedy);
         # built lazily, reused for every subsequent step
         self._slot_step_jit: Dict[Optional[SamplingParams], Any] = {}
-        self._write_slot = jax.jit(_write_slot, donate_argnums=(0,))
+        self._write_slot = jax.jit(dstack_write_slot, donate_argnums=(0,))
         self._write_slot_paged = None      # built by init_slots(paged=True)
         self._clear_slot = None
         self._clear_ring = None            # built by init_slots(paged=False)
@@ -276,8 +288,12 @@ class InferenceEngine:
         fn = self._prefill_jit.get(clen)
         if fn is None:
             api = self.api
-            fn = jax.jit(lambda p, b, _c=clen: api.prefill(p, b, _c))
+
+            def dstack_prefill(p, b):
+                return api.prefill(p, b, clen)
+            fn = jax.jit(dstack_prefill)
             self._prefill_jit[clen] = fn
+            self._built(fn, clen)
         logits, cache = fn(self.params, batch)
         self.stats.prefills += 1
         self.stats.prefill_tokens += int(
@@ -305,21 +321,25 @@ class InferenceEngine:
         carrying one or two segments must not pay the full slot count's
         attention rows."""
         if row_len is None:
-            row_len = min(self.slot_len, _pow2_at_least(
-                int(jnp.max(packed["seg_lens"]))))
+            row_len = min(self.slot_len, _pow2_at_least(int(self.host_sync(
+                jnp.max(packed["seg_lens"]), "row_len"))))
         row_len = max(1, row_len)
         key = (packed["tokens"].shape[1], row_len,
                packed["seg_starts"].shape[0])
         fn = self._packed_prefill_jit.get(key)
         if fn is None:
             api = self.api
-            fn = jax.jit(lambda p, pk, _r=row_len: api.prefill_packed(
-                p, pk, _r))
+
+            def dstack_prefill_packed(p, pk):
+                return api.prefill_packed(p, pk, row_len)
+            fn = jax.jit(dstack_prefill_packed)
             self._packed_prefill_jit[key] = fn
+            self._built(fn, key)
         logits, pcache = fn(self.params, packed)
         self.stats.prefills += 1
         self.stats.packed_prefills += 1
-        self.stats.prefill_tokens += int(jnp.sum(packed["seg_lens"]))
+        self.stats.prefill_tokens += int(self.host_sync(
+            jnp.sum(packed["seg_lens"]), "prefill_tokens"))
         return logits, pcache
 
     def prefill_chunk_packed(self, packed: Dict[str, Any],
@@ -337,17 +357,20 @@ class InferenceEngine:
         charged by the callers (a continuation is prefill progress; a
         verify chunk is not)."""
         if row_len is None:
-            row_len = min(self.slot_len, _pow2_at_least(
-                int(jnp.max(packed["seg_lens"]))))
+            row_len = min(self.slot_len, _pow2_at_least(int(self.host_sync(
+                jnp.max(packed["seg_lens"]), "row_len"))))
         row_len = max(1, row_len)
         key = (packed["tokens"].shape[1], row_len,
                packed["seg_starts"].shape[0])
         fn = self._chunk_prefill_jit.get(key)
         if fn is None:
             api = self.api
-            fn = jax.jit(lambda p, pk, cache, _r=row_len: api.prefill_chunk(
-                p, pk, cache, _r))
+
+            def dstack_prefill_chunk(p, pk, cache):
+                return api.prefill_chunk(p, pk, cache, row_len)
+            fn = jax.jit(dstack_prefill_chunk)
             self._chunk_prefill_jit[key] = fn
+            self._built(fn, key)
         return fn(self.params, packed, self._slot_cache)
 
     def decode(self, token, cache):
@@ -371,7 +394,7 @@ class InferenceEngine:
                     sub, lg, temperature=sampling.temperature,
                     top_k=sampling.top_k, top_p=sampling.top_p)
 
-            def gen(params, logits, cache, rng):
+            def dstack_generate(params, logits, cache, rng):
                 rng, tok0 = pick(rng, logits)
 
                 def body(carry, _):
@@ -386,8 +409,10 @@ class InferenceEngine:
                 # donated input can alias the output — true in-place reuse
                 return toks.swapaxes(0, 1), cache           # (B, T), cache
 
-            fn = jax.jit(gen, donate_argnums=(2,) if self.donate_cache else ())
+            fn = jax.jit(dstack_generate,
+                         donate_argnums=(2,) if self.donate_cache else ())
             self._gen_jit[key] = fn
+            self._built(fn, key)
         return fn
 
     def generate(self, batch: Dict[str, Any], max_new_tokens: int,
@@ -429,8 +454,12 @@ class InferenceEngine:
         need = max(self.cache_len, batch["tokens"].shape[1] + max_new_tokens)
         if need != self.cache_len:
             api = self.api
-            logits, cache = jax.jit(
-                lambda p, bt: api.prefill(p, bt, need))(self.params, batch)
+
+            def dstack_prefill_unbucketed(p, bt):
+                return api.prefill(p, bt, need)
+            fn = jax.jit(dstack_prefill_unbucketed)
+            self._built(fn, need)
+            logits, cache = fn(self.params, batch)
             self.stats.prefills += 1
         else:
             logits, cache = self.prefill(batch, self.cache_len)
@@ -513,12 +542,15 @@ class InferenceEngine:
             self._write_slot_paged = jax.jit(
                 _make_write_slot_paged(self.api.paged_keys, page_size),
                 donate_argnums=(0,))
-            self._clear_slot = jax.jit(_clear_slot, donate_argnums=(0, 1))
-            self._set_table_row = jax.jit(_set_table_row, donate_argnums=(0,))
+            self._clear_slot = jax.jit(dstack_clear_slot,
+                                       donate_argnums=(0, 1))
+            self._set_table_row = jax.jit(dstack_set_table_row,
+                                          donate_argnums=(0,))
         else:
             self._kv = None
             self._slot_cache = self.api.init_cache(n_slots, self.slot_len)
-            self._clear_ring = jax.jit(_clear_ring, donate_argnums=(0, 1))
+            self._clear_ring = jax.jit(dstack_clear_ring,
+                                       donate_argnums=(0, 1))
         # decode/chunk dispatches merge per-row cache leaves through a step
         # mask; page-indexed leaves (and the table, which decode never
         # writes) pass through — their dead writes land on the null page
@@ -636,7 +668,6 @@ class InferenceEngine:
         count — a one-segment chunk continuation must not pay the whole
         slot count's rows). Padding tokens carry segment id S (matched
         by no real token) and empty segments have length 0."""
-        import numpy as np
         s_max = max(1, _pow2_at_least(len(batches)))
         t = max(1, _packed_bucket(sum(lens)))
         tokens = np.zeros((1, t), np.int32)
@@ -645,7 +676,7 @@ class InferenceEngine:
         seg_lens = np.zeros((s_max,), np.int32)
         off = 0
         for i, (b, ln) in enumerate(zip(batches, lens)):
-            tokens[0, off:off + ln] = np.asarray(b["tokens"])[0]
+            tokens[0, off:off + ln] = self.host_sync(b["tokens"], "prompt")[0]
             seg_ids[off:off + ln] = i
             starts[i] = off
             seg_lens[i] = ln
@@ -754,7 +785,6 @@ class InferenceEngine:
         out-of-bounds column (ring; scatter drops them). Per-segment
         coordinates are the slot ids, padded with ``n_slots`` (out of
         bounds, dropped)."""
-        import numpy as np
         t = max(1, _packed_bucket(sum(lens)))
         # segment axis bucketed like _pack_prompts; padding entries carry
         # slot id n_slots — out of bounds on the SLOT axis, dropped
@@ -794,7 +824,6 @@ class InferenceEngine:
         ``n_slots``, clamped inside the model where its zero-length
         segment attends nothing) and ``hist_lens`` (tokens already
         resident; padding 0)."""
-        import numpy as np
         packed = self._pack_prompts(batches, lens)
         s_max = packed["seg_starts"].shape[0]
         seg_slots = np.full((s_max,), self.n_slots, np.int32)
@@ -813,7 +842,6 @@ class InferenceEngine:
         Table rows are the slot's CURRENT pages: the chunk's destination
         pages were reserved before the dispatch (admission horizon or an
         executed grow)."""
-        import numpy as np
         assert self.paged
         t = max(1, _packed_bucket(sum(lens)))
         s_max = max(1, _pow2_at_least(len(slots)))
@@ -895,8 +923,7 @@ class InferenceEngine:
         """Host read of the slot's pending token (the next decode input,
         not yet emitted). The planner captures it once per request as the
         speculation seed; a per-slot sync, so gated on spec serving."""
-        import numpy as np
-        return int(np.asarray(self._last_tok[slot]))
+        return int(self.host_sync(self._last_tok[slot], "last_token"))
 
     def draft_synced(self, slot: int) -> bool:
         """True when the slot's draft twin exists and sits at the same
@@ -924,7 +951,8 @@ class InferenceEngine:
         if self._copy_page is None:
             self._copy_page = jax.jit(_make_copy_page(self.api.paged_keys),
                                       donate_argnums=(0,))
-            self._alias_slot = jax.jit(_alias_slot, donate_argnums=(0,))
+            self._alias_slot = jax.jit(dstack_alias_slot,
+                                       donate_argnums=(0,))
         return self.prefix_cache
 
     def warm_prefix_ops(self) -> None:
@@ -1011,8 +1039,7 @@ class InferenceEngine:
         row = jnp.asarray(self._kv.table_row(slot), jnp.int32)
         self._slot_cache = self._alias_slot(
             self._slot_cache, jnp.int32(slot), row, jnp.int32(covered))
-        import numpy as np
-        toks = np.asarray(batch["tokens"])[0]
+        toks = self.host_sync(batch["tokens"], "prompt")[0]
         self._last_tok = self._last_tok.at[slot].set(
             jnp.int32(int(toks[covered])))
         self._slot_active[slot] = True
@@ -1025,7 +1052,7 @@ class InferenceEngine:
         self.stats.prefix_hit_tokens += covered
         if self.telemetry is not None:
             self.telemetry.instant(
-                self.telemetry.engine_track(self), "prefix_hit",
+                self._track, "prefix_hit",
                 slot=slot, covered=covered,
                 cow=int(hit.cow_src is not None))
         return slot
@@ -1077,7 +1104,7 @@ class InferenceEngine:
         self.stats.dedup_pages += freed
         if self.telemetry is not None:
             self.telemetry.instant(
-                self.telemetry.engine_track(self), "prefix_dedup",
+                self._track, "prefix_dedup",
                 slot=slot, pages=freed)
         return freed
 
@@ -1178,11 +1205,10 @@ class InferenceEngine:
             lens.append(ln)
         slots = [slot for slot, _, _ in chunks]
         if self.chunk_capable():
-            import numpy as np
             offs = [self._slot_pos[slot] for slot in slots]
             new_lens = [ln - off for ln, off in zip(lens, offs)]
             news = [{"tokens": jnp.asarray(
-                np.asarray(b["tokens"])[:, off:ln])}
+                self.host_sync(b["tokens"], "prompt")[:, off:ln])}
                 for (_, b, _), off, ln in zip(chunks, offs, lens)]
             packed = self._pack_chunks(news, new_lens, slots, offs)
             seg_logits, _, pcache = self.prefill_chunk_packed(
@@ -1259,8 +1285,8 @@ class InferenceEngine:
         # (static) index vectors, so the verify dispatch queues
         # back-to-back behind the draft scan with no host sync — and no
         # separate gather dispatch — between them.
-        def scan_fn(params, tok, cache, masks, tok_t, idx_t, idx_d,
-                    step_idx, slot_idx):
+        def dstack_draft_scan(params, tok, cache, masks, tok_t, idx_t,
+                              idx_d, step_idx, slot_idx):
             # teacher-force inside the dispatch: pin each paired row's
             # pending token to the target's — the host never reads
             # either engine's _last_tok to start a round
@@ -1278,7 +1304,7 @@ class InferenceEngine:
             verify = jnp.where(step_idx == 0, seed, drafted)[None, :]
             return props, verify, tok, cache
 
-        self._draft_scan = jax.jit(scan_fn, donate_argnums=(2,))
+        self._draft_scan = jax.jit(dstack_draft_scan, donate_argnums=(2,))
 
         # end-of-round commit fused into ONE dispatch: the packed-segment
         # scatter plus the fixups (pin both engines' pending tokens to
@@ -1289,8 +1315,8 @@ class InferenceEngine:
         # dest pages/offsets].
         ws = _make_write_segments(self.api.paged_keys)
 
-        def commit_fn(slot_cache, last_tok, lt_d, pos_d, pcache,
-                      seg_logits, aux, segs, tables, idx_t, idx_d):
+        def dstack_spec_commit(slot_cache, last_tok, lt_d, pos_d, pcache,
+                               seg_logits, aux, segs, tables, idx_t, idx_d):
             n, s = idx_t.shape[0], segs.shape[0]
             gv, dp = aux[:n], aux[n:2 * n]
             new_pos = aux[2 * n:2 * n + s]
@@ -1305,7 +1331,8 @@ class InferenceEngine:
                     lt_d.at[idx_d].set(gv, mode="drop"),
                     pos_d.at[idx_d].set(dp, mode="drop"))
 
-        self._spec_commit = jax.jit(commit_fn, donate_argnums=(0, 1, 2, 3))
+        self._spec_commit = jax.jit(dstack_spec_commit,
+                                    donate_argnums=(0, 1, 2, 3))
         self._spec_consts = {}
         self._spec_dest = {}
         return draft
@@ -1332,7 +1359,6 @@ class InferenceEngine:
         way (ring pos rewind) and both ends hold the bonus token as
         their pending input, keeping the pair in lockstep for the next
         round."""
-        import numpy as np
         tel = self.telemetry
         draft = self._draft
         slots = [s for s, _, _ in entries]
@@ -1356,7 +1382,6 @@ class InferenceEngine:
             chosen = set(order)
             draft._slot_free = order + [s for s in draft._slot_free
                                         if s not in chosen]
-            t0 = tel.t0() if tel is not None else 0.0
             got = draft.insert_many(
                 [{"tokens": jnp.asarray(np.asarray(toks, np.int32)[None, :])}
                  for _, toks in admit],
@@ -1364,9 +1389,6 @@ class InferenceEngine:
             assert got == order, "draft twin landed on the wrong slot"
             self._draft_ready.update(order)
             res.dispatches += 1
-            if tel is not None:
-                tel.dispatch_done(draft, "spec_admit", len(admit), t0,
-                                  sync=draft._slot_cache, segs=len(admit))
 
         # round constants: every index vector, scan mask, and segment-
         # layout array depends only on (slots, ks) — identical for every
@@ -1430,7 +1452,6 @@ class InferenceEngine:
 
         # ---- draft: k+1 masked steps (teacher-forcing fused into the
         # scan prologue), one dispatch, nothing read back yet
-        t0 = tel.t0() if tel is not None else 0.0
         props, verify_tok, dtok, dcache = self._draft_scan(
             draft.params, draft._last_tok, draft._slot_cache,
             consts["mask"], self._last_tok, idx_j, idx_d,
@@ -1438,9 +1459,6 @@ class InferenceEngine:
         draft._last_tok = dtok
         draft._slot_cache = dcache
         res.dispatches += 1
-        if tel is not None:
-            tel.dispatch_done(draft, "spec_draft", consts["n_steps"], t0,
-                              sync=props, slots=len(slots))
 
         # ---- verify: [t, d_1..d_k] per slot, one incremental chunk.
         # The token row is gathered from the draft's proposals ON
@@ -1457,20 +1475,15 @@ class InferenceEngine:
             "seg_slots": consts["seg_slots"],
             "hist_lens": jnp.asarray(hist),
         }
-        t0 = tel.t0() if tel is not None else 0.0
         seg_logits, tok_argmax, pcache = self.prefill_chunk_packed(
             packed, row_len=consts["row_len"])
         res.dispatches += 1
-        if tel is not None:
-            tel.dispatch_done(self, "spec_verify",
-                              _packed_bucket(sum(vlens)), t0,
-                              sync=(seg_logits, pcache),
-                              segs=len(slots), tokens=sum(vlens))
 
         # ---- accept / commit / rollback: the round's ONLY host reads —
         # both dispatches are already in flight when these block
-        props_h = np.asarray(props).T.tolist()   # per-slot proposal lists
-        amax = np.asarray(tok_argmax).tolist()
+        # per-slot proposal lists
+        props_h = self.host_sync(props, "spec_proposals").T.tolist()
+        amax = self.host_sync(tok_argmax, "spec_argmax").tolist()
         new_pos = np.zeros((s_max,), np.int32)
         gvals = np.zeros((self.n_slots,), np.int32)
         dpos = np.zeros((self.n_slots,), np.int32)
@@ -1545,7 +1558,7 @@ class InferenceEngine:
                         and slot not in res.done):
                     res.done.append(slot)
         if tel is not None:
-            tel.instant(tel.engine_track(self), "spec_round",
+            tel.instant(self._track, "spec_round",
                         slots=len(slots), drafted=drafted_total,
                         accepted=accepted_total, rollbacks=n_roll)
 
@@ -1568,12 +1581,34 @@ class InferenceEngine:
     # ------------------------------------------------------- telemetry
     def attach_telemetry(self, tel) -> None:
         """Arm (or with None, disarm) the serving telemetry plane
-        (``repro.serving.telemetry.Telemetry``) on this engine. Like
-        ``attach_faults``, attach AFTER warmup: timing covers only warm
-        executables. Timing blocks on dispatch outputs
-        (``block_until_ready``), which changes wall-clock pipelining but
-        never values, dispatch counts, or compilation."""
+        (``repro.serving.telemetry.Telemetry``) on this engine: its
+        ``TraceRecorder`` then also records the engine's spans and
+        instants. Recording never blocks on the device, so it changes no
+        value, dispatch count or compilation."""
         self.telemetry = tel
+
+    def host_sync(self, x, site: str) -> np.ndarray:
+        """``x`` on the host. Every device-to-host read of the engine and
+        its planner goes through here: a device array holds the host until
+        the device has produced it, so such a read opens a
+        ``dstack.engine.host_sync`` span naming its ``site`` and counts in
+        ``stats.host_syncs``. A host array passes through uncounted."""
+        if not isinstance(x, jax.Array):
+            return np.asarray(x)
+        with span(self.telemetry, self._track, "dstack.engine.host_sync",
+                  site=site):
+            out = np.asarray(x)
+        self.stats.host_syncs += 1
+        return out
+
+    def _built(self, fn, key) -> None:
+        """A miss of one of the engine's jit dictionaries: a new
+        executable of the jitted step ``fn`` at bucket ``key``."""
+        self.stats.executables_built += 1
+        if self.telemetry is not None:
+            self.telemetry.instant(self._track, "executable_built",
+                                   cat="compile", step=fn.__name__,
+                                   key=str(key))
 
     def recover(self) -> int:
         """Engine reset after an unrecoverable fault (retries exhausted,
@@ -1606,8 +1641,8 @@ class InferenceEngine:
         self.check_page_invariants()
         self.stats.engine_resets += 1
         if self.telemetry is not None:
-            self.telemetry.instant(self.telemetry.engine_track(self),
-                                   "engine_reset", dropped=dropped)
+            self.telemetry.instant(self._track, "engine_reset",
+                                   dropped=dropped)
         return dropped
 
     def check_page_invariants(self) -> bool:
@@ -1658,9 +1693,8 @@ class InferenceEngine:
                 self.stats.engine_retries += 1
                 attempts += 1
                 if self.telemetry is not None:
-                    self.telemetry.instant(
-                        self.telemetry.engine_track(self), "retry",
-                        attempt=attempts)
+                    self.telemetry.instant(self._track, "retry",
+                                           attempt=attempts)
                 if attempts > self.retry_limit:
                     raise EngineFault(
                         f"dispatch fault persisted past {self.retry_limit} "
@@ -1668,20 +1702,17 @@ class InferenceEngine:
                 if self.retry_backoff_s > 0:
                     import time
                     time.sleep(self.retry_backoff_s * (2 ** (attempts - 1)))
-        tel = self.telemetry
-        if tel is None or tel.trace is None:
-            return self._execute_plan(plan)
-        with tel.trace.span(tel.engine_track(self), "execute",
-                            admissions=len(plan.admissions),
-                            decodes=len(plan.decodes),
-                            frees=len(plan.frees), cancels=len(plan.cancels),
-                            preemptions=len(plan.preemptions),
-                            grows=len(plan.grows)):
+        with span(self.telemetry, self._track, "dstack.engine.execute",
+                  admissions=len(plan.admissions), decodes=len(plan.decodes),
+                  frees=len(plan.frees), cancels=len(plan.cancels),
+                  preemptions=len(plan.preemptions), grows=len(plan.grows)):
             return self._execute_plan(plan)
 
     def _execute_plan(self, plan) -> "Any":
-        import numpy as np
-
+        """The plan's work, one ``dstack.engine.<part>`` span around the
+        host packing and enqueue of each part (category ``dispatch``);
+        the only waits are the ``host_sync`` reads inside them and the
+        decode's token read-back after its span."""
         from repro.serving.plan import StepResult
         res = StepResult()
         for slot in plan.frees:
@@ -1690,23 +1721,21 @@ class InferenceEngine:
             self.free(slot)
         for slot in plan.preemptions:
             self.free(slot)
-        tel = self.telemetry
+        tel, track = self.telemetry, self._track
         failed: set = set()
         if plan.grows:
-            t0 = tel.t0() if tel is not None else 0.0
-            for slot, upto in plan.grows:
-                try:
-                    self.grow_slot(slot, upto)
-                except OutOfPages:
-                    # injected (or genuinely racy) allocator failure: the
-                    # slot is untouched but its next write is unbacked —
-                    # skip its chunk/decode this tick, report for requeue
-                    failed.add(slot)
-                    res.failed_grows.append(slot)
-            if tel is not None:
-                tel.dispatch_done(self, "grow", len(plan.grows), t0,
-                                  sync=self._slot_cache,
-                                  failed=len(res.failed_grows))
+            with span(tel, track, "dstack.engine.grow", cat="dispatch",
+                      slots=len(plan.grows)):
+                for slot, upto in plan.grows:
+                    try:
+                        self.grow_slot(slot, upto)
+                    except OutOfPages:
+                        # injected (or genuinely racy) allocator failure:
+                        # the slot is untouched but its next write is
+                        # unbacked — skip its chunk/decode this tick,
+                        # report for requeue
+                        failed.add(slot)
+                        res.failed_grows.append(slot)
         alias = [c for c in plan.admissions
                  if c.slot is None and getattr(c, "alias", None) is not None]
         first = [c for c in plan.admissions
@@ -1727,69 +1756,58 @@ class InferenceEngine:
             except OutOfPages:
                 self.prefix_cache.release_hit(c.alias)
                 if tel is not None:
-                    tel.instant(tel.engine_track(self),
-                                "alias_admission_failed", rid=c.rid)
+                    tel.instant(track, "alias_admission_failed", rid=c.rid)
         if first:
-            t0 = tel.t0() if tel is not None else 0.0
             try:
-                slots = self.insert_many(
-                    [c.batch for c in first],
-                    n_tokens=[c.n_tokens for c in first],
-                    reserve_tokens=[c.reserve_tokens for c in first])
+                with span(tel, track, "dstack.engine.prefill",
+                          cat="dispatch", segs=len(first)):
+                    slots = self.insert_many(
+                        [c.batch for c in first],
+                        n_tokens=[c.n_tokens for c in first],
+                        reserve_tokens=[c.reserve_tokens for c in first])
                 res.admitted.update(
                     {c.rid: s for c, s in zip(first, slots)})
                 res.dispatches += 1
-                if tel is not None:
-                    ntok = sum(int(c.batch["tokens"].shape[1])
-                               for c in first)
-                    tel.dispatch_done(self, "admission_prefill",
-                                      _packed_bucket(ntok), t0,
-                                      sync=(self._slot_cache,
-                                            self._last_tok),
-                                      segs=len(first), tokens=ntok)
             except OutOfPages:
                 # all-or-nothing rollback already ran: no slot was touched;
                 # the planner requeues the whole staged batch
                 res.admission_failed = True
                 if tel is not None:
-                    tel.instant(tel.engine_track(self), "admission_failed",
-                                segs=len(first))
+                    tel.instant(track, "admission_failed", segs=len(first))
         if cont:
-            t0 = tel.t0() if tel is not None else 0.0
-            self.chunk_append([(c.slot, c.batch, c.final) for c in cont])
+            with span(tel, track, "dstack.engine.chunk", cat="dispatch",
+                      segs=len(cont)):
+                self.chunk_append([(c.slot, c.batch, c.final) for c in cont])
             res.dispatches += 1
-            if tel is not None:
-                ntok = sum(int(c.batch["tokens"].shape[1]) for c in cont)
-                tel.dispatch_done(self, "chunk_prefill",
-                                  _packed_bucket(ntok), t0,
-                                  sync=(self._slot_cache, self._last_tok),
-                                  segs=len(cont), tokens=ntok)
         decodes = [s for s in plan.decodes if s not in failed]
         forced = [(s, t) for s, t in getattr(plan, "forced", [])
                   if s not in failed]
         if decodes or forced:
-            t0 = tel.t0() if tel is not None else 0.0
-            # teacher-forced catch-up slots join THE decode dispatch: the
-            # planner pre-picked this tick's prompt token per slot; the
-            # masked step writes its K/V at pos (exactly what prefill
-            # would write there) and advances pos. Forced outputs never
-            # reach res.tokens — nothing was generated for the stream
-            for s, t in forced:
-                self._last_tok = self._last_tok.at[s].set(jnp.int32(int(t)))
-            toks, done = self.step(decodes + [s for s, _ in forced],
-                                   forced={s for s, _ in forced})
-            t = np.asarray(toks)
+            with span(tel, track, "dstack.engine.decode", cat="dispatch",
+                      slots=len(decodes), forced=len(forced)):
+                # teacher-forced catch-up slots join THE decode dispatch:
+                # the planner pre-picked this tick's prompt token per
+                # slot; the masked step writes its K/V at pos (exactly
+                # what prefill would write there) and advances pos.
+                # Forced outputs never reach res.tokens — nothing was
+                # generated for the stream
+                for s, t in forced:
+                    self._last_tok = self._last_tok.at[s].set(
+                        jnp.int32(int(t)))
+                toks, done = self.step(decodes + [s for s, _ in forced],
+                                       forced={s for s, _ in forced})
+            t = self.host_sync(toks, "decode")
             res.tokens = {int(s): int(t[s]) for s in decodes}
             res.done = list(done)
             res.dispatches += 1
-            if tel is not None:
-                tel.dispatch_done(self, "decode",
-                                  len(decodes) + len(forced), t0,
-                                  sync=toks, forced=len(forced))
         spec = [e for e in getattr(plan, "spec", ())
                 if e[0] not in failed]
         if spec:
-            self._spec_round(spec, res)
+            # one span over the round: its read-backs are host_sync
+            # children between the draft/verify enqueue and the commit
+            with span(tel, track, "dstack.engine.spec", cat="dispatch",
+                      slots=len(spec)):
+                self._spec_round(spec, res)
         return res
 
     def _get_slot_step(self, sampling: Optional[SamplingParams]):
@@ -1798,17 +1816,19 @@ class InferenceEngine:
             api = self.api
             skip = self._step_skip
             if sampling is None:
-                fn = jax.jit(
-                    lambda p, tok, cache, active: _slot_decode_step(
-                        api, skip, p, tok, cache, active),
-                    donate_argnums=self._donate_cache_argnums)
+                def dstack_slot_step(p, tok, cache, active):
+                    return _slot_decode_step(api, skip, p, tok, cache,
+                                             active)
+                fn = jax.jit(dstack_slot_step,
+                             donate_argnums=self._donate_cache_argnums)
             else:
-                fn = jax.jit(
-                    lambda p, tok, cache, active, rng, _s=sampling:
-                    _slot_decode_step(api, skip, p, tok, cache, active,
-                                      rng, _s),
-                    donate_argnums=self._donate_cache_argnums)
+                def dstack_slot_step_sampled(p, tok, cache, active, rng):
+                    return _slot_decode_step(api, skip, p, tok, cache,
+                                             active, rng, sampling)
+                fn = jax.jit(dstack_slot_step_sampled,
+                             donate_argnums=self._donate_cache_argnums)
             self._slot_step_jit[sampling] = fn
+            self._built(fn, sampling)
         return fn
 
     def step(self, slots: Optional[List[int]] = None,
@@ -1833,7 +1853,6 @@ class InferenceEngine:
         token's K/V and advances ``pos`` exactly like prefill would, but
         the slot's generated counter — and the emitted-token accounting —
         are untouched: nothing was sampled for the stream."""
-        import numpy as np
         if slots is None:
             mask = self._active_mask
             stepped = [s for s, a in enumerate(self._slot_active) if a]
@@ -1969,7 +1988,7 @@ def _merge_rows(new, old, mask, skip):
     """Keep ``new`` cache leaves only for rows in ``mask``; rows outside
     it retain ``old`` bit-for-bit. Per-row leaves carry batch at axis 0
     (1-D ``pos``) or axis 1 (stacked ``(layers, B, ...)``) — the same
-    layout rule ``_write_slot`` relies on. Leaves in ``skip`` (paged K/V
+    layout rule ``dstack_write_slot`` relies on. Leaves in ``skip`` (paged K/V
     pools, the block table) are page-indexed, not row-indexed, and pass
     through: masked-off rows' dead writes there land at a not-yet-valid
     position (always overwritten before any read attends to it) or on
@@ -2007,7 +2026,7 @@ def _slot_decode_step(api, skip, params, tok, cache, mask, rng=None,
     return jnp.where(mask, nxt, tok), cache
 
 
-def _write_slot(big, one, slot):
+def dstack_write_slot(big, one, slot):
     """Write a batch-1 cache into row ``slot`` of a slotted cache. Every
     stacked leaf is (layers, batch, ...); the position vector is (batch,)."""
     def wr(b_leaf, o_leaf):
@@ -2028,7 +2047,7 @@ def _make_write_slot_paged(paged_keys, page_size: int):
     into the never-read null page)."""
     paged_keys = frozenset(paged_keys)
 
-    def write(big, one, slot, table_row):
+    def dstack_write_slot_paged(big, one, slot, table_row):
         max_pages = table_row.shape[0]
         out = {}
         for key, b_leaf in big.items():
@@ -2049,7 +2068,7 @@ def _make_write_slot_paged(paged_keys, page_size: int):
                     b_leaf, o_leaf, slot, axis=axis)
         return out
 
-    return write
+    return dstack_write_slot_paged
 
 
 def _make_write_segments(paged_keys):
@@ -2064,8 +2083,8 @@ def _make_write_segments(paged_keys):
     per packed-token bucket — the batch's segment count never retraces."""
     paged_keys = frozenset(paged_keys)
 
-    def write(cache, last_tok, pcache, logits, dest0, dest1, seg_slots,
-              table_rows):
+    def dstack_write_segments(cache, last_tok, pcache, logits, dest0, dest1,
+                              seg_slots, table_rows):
         paged = "block_tables" in cache
         out = {}
         for key, b_leaf in cache.items():
@@ -2089,10 +2108,10 @@ def _make_write_segments(paged_keys):
             jnp.argmax(logits, -1).astype(jnp.int32))
         return out, new_last
 
-    return write
+    return dstack_write_segments
 
 
-def _set_table_row(cache, slot, table_row):
+def dstack_set_table_row(cache, slot, table_row):
     """Push a grown slot's block-table row to the device (lazy page
     reservation: pages appear as decode/chunk writes cross page
     boundaries). One static shape — the row is always the full padded
@@ -2110,7 +2129,7 @@ def _make_copy_page(paged_keys):
     hit admission (only when the match ends inside a page)."""
     paged_keys = frozenset(paged_keys)
 
-    def copy(cache, src, dst):
+    def dstack_copy_page(cache, src, dst):
         out = dict(cache)
         for key in sorted(paged_keys):
             leaf = out[key]            # (layers, KV, pages, page_size, D)
@@ -2119,10 +2138,10 @@ def _make_copy_page(paged_keys):
                 leaf, page, dst, axis=2)
         return out
 
-    return copy
+    return dstack_copy_page
 
 
-def _alias_slot(cache, slot, table_row, pos):
+def dstack_alias_slot(cache, slot, table_row, pos):
     """Point a hit admission's slot at its aliased + fresh pages and set
     its position to the covered prefix length — the ONLY device writes a
     fully-page-aligned hit needs (COW adds one page copy). Like
@@ -2134,7 +2153,7 @@ def _alias_slot(cache, slot, table_row, pos):
     return cache
 
 
-def _clear_slot(cache, mask, slot):
+def dstack_clear_slot(cache, mask, slot):
     """Park a freed slot: position 0 + whole table row on the null page,
     so its dead writes can never alias a page later granted to another
     sequence. The active-mask clear rides the same dispatch — a separate
@@ -2145,7 +2164,7 @@ def _clear_slot(cache, mask, slot):
     return cache, mask.at[slot].set(False)
 
 
-def _clear_ring(pos, mask, slot):
+def dstack_clear_ring(pos, mask, slot):
     """Ring-slot free: position and active-mask clear in one dispatch."""
     return pos.at[slot].set(0), mask.at[slot].set(False)
 

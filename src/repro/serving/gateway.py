@@ -30,12 +30,17 @@ Two clocks: virtual (default — time jumps event-to-event exactly like
 ``serve_ticks``) and **wall** (``wall_clock=True`` — the loop sleeps
 until ``perf_counter`` reaches each event time and stamps ticks with
 real elapsed seconds, so the planner's TTFT/TBT/deadline arithmetic
-runs against the host clock and PR 7's ``StepTimers``/roofline report
-validate measured-vs-modeled per step).
+runs against the host clock; the tick server then observes each tick's
+tokens at the gateway's clock read after ``execute``, when they exist).
+Each request is stamped ``delivered`` when the gateway hands it to the
+tick server, on the clock its ``arrival`` uses.
 
-Every edge lands as a telemetry instant on the model's queue track
-when a ``Telemetry`` plane is attached, and costs one ``is None``
-check when not — the zero-cost-when-detached contract.
+Handing an arrival over and pumping tokens into client streams open
+the ``dstack.gateway.deliver`` and ``dstack.gateway.pump`` spans
+(``repro.serving.telemetry.span``), on the tick track between ticks.
+Every edge lands as a telemetry
+instant on the model's queue track when a ``Telemetry`` plane is
+attached, and costs one ``is None`` check when not.
 """
 from __future__ import annotations
 
@@ -46,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from repro.serving.plan import StepPlanner, TickServer
 from repro.serving.request import Request
+from repro.serving.telemetry import span
 
 _EPS = 1e-12
 _DONE = object()
@@ -163,7 +169,8 @@ class AsyncGateway:
         self.server = TickServer(
             planner, prompt_fn if prompt_fn is not None else self._batch_of,
             tick_dt=tick_dt, faults=faults, on_tick=on_tick,
-            stall_limit=stall_limit)
+            stall_limit=stall_limit,
+            clock=self._elapsed if wall_clock else None)
         self.streams: Dict[int, TokenStream] = {}
         self._live: Dict[int, TokenStream] = {}
         self._pending: List[Request] = []     # scheduled trace arrivals
@@ -228,7 +235,11 @@ class AsyncGateway:
             raise DeadlineRejection(req)
         self._batches[req.rid] = batch
         self._tel("arrival", req)
-        if not self.planner.submit(req, batch):
+        req.delivered = now
+        with span(self.planner.telemetry, self.planner._track,
+                  "dstack.gateway.deliver", rid=req.rid):
+            ok = self.planner.submit(req, batch)
+        if not ok:
             self._batches.pop(req.rid, None)
             raise ShedRejection(req)
         st = TokenStream(self, req)
@@ -261,6 +272,11 @@ class AsyncGateway:
         high-water mark makes requeue replays invisible: a cleared
         planner stream re-emits its (bit-exact) prefix below the mark
         and only genuinely new tokens reach the client."""
+        with span(self.planner.telemetry, self.planner._track,
+                  "dstack.gateway.pump"):
+            self._pump_streams()
+
+    def _pump_streams(self) -> None:
         done: List[int] = []
         for rid, st in self._live.items():
             toks = self.planner.streams.get(rid)
@@ -282,7 +298,10 @@ class AsyncGateway:
         # hooks' deliver (planner.submit via TickServer.deliver — which
         # handles the shed branch and its accounting)
         self._tel("arrival", req)
-        self.server.deliver(req)
+        req.delivered = self.now
+        with span(self.planner.telemetry, self.planner._track,
+                  "dstack.gateway.deliver", rid=req.rid):
+            self.server.deliver(req)
 
     async def run(self, *, hold_open: bool = False) -> None:
         """Serve until drained (trace mode) or until ``close()`` then

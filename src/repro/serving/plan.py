@@ -76,13 +76,12 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.serving.faults import EngineFault
 from repro.serving.metrics import ModelPoolMetrics
 from repro.serving.request import Request, RequestQueue
+from repro.serving.telemetry import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -461,9 +460,13 @@ class StepPlanner:
         self._spec_ticks = 0
         self._spec_planned: Dict[int, int] = {}
         # telemetry plane (repro.serving.telemetry.Telemetry), set by
-        # EnginePool.attach_telemetry or directly by the tick plane;
-        # None = zero-cost (one attribute check per lifecycle event)
+        # EnginePool.attach_telemetry or directly by the tick plane:
+        # its ring also records the planner's spans, on the tick track,
+        # and lifecycle instants. None = one attribute check per event
         self.telemetry = None
+        model = (queue.model if queue is not None
+                 else engine.cfg.name if engine is not None else "planner")
+        self._track = f"tick/{model}"
         # tiered, tenant-fair admission (None = strict FIFO, the exact
         # legacy pop order — every existing plane takes this branch)
         self.admission = (TieredAdmission(
@@ -587,11 +590,10 @@ class StepPlanner:
             return None
         return eng.prefix_cache
 
-    @staticmethod
-    def _host_tokens(r: _Resident) -> List[int]:
+    def _host_tokens(self, r: _Resident) -> List[int]:
         if r.host_tokens is None:
-            r.host_tokens = [int(t)
-                             for t in np.asarray(r.batch["tokens"])[0]]
+            r.host_tokens = [int(t) for t in self.engine.host_sync(
+                r.batch["tokens"], "prompt")[0]]
         return r.host_tokens
 
     def _min_covered(self, eng, prompt_len: int) -> int:
@@ -612,8 +614,8 @@ class StepPlanner:
         if freed:
             eng = self.engine
             if eng.telemetry is not None:
-                eng.telemetry.instant(eng.telemetry.engine_track(eng),
-                                      "prefix_evict", pages=freed)
+                eng.telemetry.instant(eng._track, "prefix_evict",
+                                      pages=freed)
         return pages_avail + freed
 
     def _register_prompts(self) -> None:
@@ -646,7 +648,13 @@ class StepPlanner:
     def build(self, now: float) -> StepPlan:
         """Emit this tick's plan. Mutates planner bookkeeping under the
         assumption the plan WILL be executed (the tick loop always does:
-        build → execute → observe)."""
+        build → execute → observe). A request whose first chunk the plan
+        carries is stamped ``scheduled`` at ``now`` (once: a requeued
+        request keeps its first stamp)."""
+        with span(self.telemetry, self._track, "dstack.planner.build"):
+            return self._build(now)
+
+    def _build(self, now: float) -> StepPlan:
         eng, q, cfg = self.engine, self.queue, self.config
         self._now = now
         plan = StepPlan()
@@ -817,6 +825,8 @@ class StepPlanner:
                 pages_avail=pages_avail, budget_left=budget_left)
             for req, batch, budget, c, reserve, hit, toks in kept:
                 p = _prompt_tokens(batch)
+                if req.scheduled < 0:
+                    req.scheduled = now
                 if hit is not None:
                     # prefix-cache hit: zero-cost leading chunk — no
                     # prefill tokens computed, no chunk budget charged.
@@ -1074,7 +1084,8 @@ class StepPlanner:
             hit = None
             toks: Optional[List[int]] = None
             if cache is not None and eng.paged:
-                toks = [int(t) for t in np.asarray(batch["tokens"])[0]]
+                toks = [int(t) for t in
+                        eng.host_sync(batch["tokens"], "prompt")[0]]
                 hit = cache.match(toks, max_covered=p - 1,
                                   min_covered=self._min_covered(eng, p))
             if eng.paged:
@@ -1171,8 +1182,9 @@ class StepPlanner:
     # --------------------------------------------------------- feedback
     def observe(self, res: StepResult, now: float) -> List[Request]:
         """Fold one tick's ``StepResult`` back: bind admitted slots,
-        record emitted tokens, complete exhausted requests (their slots
-        free at the NEXT tick's plan). Returns the completed requests.
+        record emitted tokens (a request's first stamps ``first_token``
+        at ``now``), complete exhausted requests (their slots free at the
+        NEXT tick's plan). Returns the completed requests.
 
         Failure feedback: slots whose lazy grow failed
         (``failed_grows``) recompute-requeue — their slot frees at the
@@ -1180,6 +1192,10 @@ class StepPlanner:
         (``admission_failed``, all-or-nothing rollback) requeues every
         staged request. Neither loses a request — previously a staged
         rid missing from ``admitted`` silently vanished."""
+        with span(self.telemetry, self._track, "dstack.planner.observe"):
+            return self._observe(res, now)
+
+    def _observe(self, res: StepResult, now: float) -> List[Request]:
         for slot in res.failed_grows:
             r = self._resident.pop(slot, None)
             if r is None:
@@ -1367,7 +1383,8 @@ class StepPlanner:
             for req, _ in kept:
                 batch = self._prompts.get(req.rid)
                 toks = (None if batch is None else
-                        [int(t) for t in np.asarray(batch["tokens"])[0]])
+                        [int(t) for t in
+                         eng.host_sync(batch["tokens"], "prompt")[0]])
                 hot.append(toks is not None and cache.peek(
                     toks, max_covered=prompt_len - 1) >= floor)
             if any(hot) and not all(hot):
@@ -1392,7 +1409,8 @@ class StepPlanner:
             p = _prompt_tokens(batch)
             hit = None
             if cache is not None and eng.paged:
-                toks = [int(t) for t in np.asarray(batch["tokens"])[0]]
+                toks = [int(t) for t in
+                        eng.host_sync(batch["tokens"], "prompt")[0]]
                 hit = cache.match(toks, max_covered=p - 1,
                                   min_covered=self._min_covered(eng, p))
             if hit is not None:
@@ -1431,17 +1449,24 @@ class TickServer:
     called before each tick's plan — the chaos suite drives cancellations
     through it. ``stall_limit`` arms a no-progress watchdog: that many
     consecutive ticks with an empty result force a recovery rather than
-    spinning forever."""
+    spinning forever.
+
+    ``clock`` (None = virtual time) is the caller's clock on a wall-clock
+    plane: the result of a tick is observed at ``clock()`` read after
+    ``execute`` returned — when its tokens exist — rather than at the
+    tick's start ``now``."""
 
     def __init__(self, planner: StepPlanner, prompt_fn,
                  tick_dt: float = 1e-3, faults=None, on_tick=None,
-                 stall_limit: Optional[int] = None):
+                 stall_limit: Optional[int] = None,
+                 clock: Optional[Callable[[], float]] = None):
         self.planner = planner
         self.prompt_fn = prompt_fn
         self.tick_dt = tick_dt
         self.faults = faults
         self.on_tick = on_tick
         self.stall_limit = stall_limit
+        self.clock = clock
         self.ticks = 0
         self.dispatches = 0
         self.peak_resident = 0
@@ -1458,9 +1483,6 @@ class TickServer:
         # counterpart of tick_walls: what chunking actually bounds)
         self.tick_prefill: List[int] = []
         self._next_tick = 0.0
-        q = planner.queue
-        self._track = (f"tick/{q.model}" if q is not None
-                       else f"tick/{planner.engine.cfg.name}")
 
     @property
     def telemetry(self):
@@ -1494,15 +1516,14 @@ class TickServer:
     def fire(self, now: float, epsilon: float = 1e-12) -> int:
         if not self.planner.busy():
             return 0
-        tel = self.planner.telemetry
-        if tel is None or tel.trace is None:
-            return self._fire(now, None)
-        # one span per executed tick on the server's own track; the
-        # engine's execute/dispatch spans nest on the engine track
-        with tel.trace.span(self._track, "tick", tick=self.ticks):
-            return self._fire(now, tel.trace)
+        # one span per executed tick on the planner's track (its build
+        # and observe spans nest there; the engine's on the engine track)
+        planner = self.planner
+        with span(planner.telemetry, planner._track, "dstack.tick",
+                  tick=self.ticks):
+            return self._fire(now)
 
-    def _fire(self, now: float, trace) -> int:
+    def _fire(self, now: float) -> int:
         import time as _time
         # the tick always reschedules, whatever happens below — a faulted
         # tick that forgot to advance _next_tick would spin the loop at
@@ -1510,11 +1531,7 @@ class TickServer:
         self._next_tick = now + self.tick_dt
         if self.on_tick is not None:
             self.on_tick(self, now)
-        if trace is None:
-            plan = self.planner.build(now)
-        else:
-            with trace.span(self._track, "plan"):
-                plan = self.planner.build(now)
+        plan = self.planner.build(now)
         eng = self.planner.engine
         if self.faults is not None and self.faults.stuck():
             # watchdog-killed tick: the plan's bookkeeping was already
@@ -1532,7 +1549,8 @@ class TickServer:
             self._recover(now)
             return 1
         wall = _time.perf_counter() - t0
-        self.planner.observe(res, now)
+        self.planner.observe(res, now if self.clock is None
+                             else self.clock())
         self.ticks += 1
         self.dispatches += res.dispatches
         self.peak_resident = max(self.peak_resident,

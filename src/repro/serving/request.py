@@ -35,13 +35,20 @@ class Request:
     # recompute from scratch. The queue's per-cause counters (not this
     # field) are the accounting source of truth; state is introspection.
     state: str = dataclasses.field(compare=False, default="pending")
-    # streaming progress: virtual time the FIRST decode token was
-    # observed (-1.0 = none yet) and tokens emitted so far. Reset on
-    # every requeue (preemption / failed grow / engine reset) — recompute
-    # discards emitted tokens, so TTFT is the time to the first token of
-    # the attempt that actually completed, matching what a streaming
-    # client replaying the stream would see.
+    # streaming progress: time the FIRST decode token was observed (-1.0
+    # = none yet; on the wall-clock gateway, after the tick's execute
+    # returned) and tokens emitted so far. Reset on every requeue
+    # (preemption / failed grow / engine reset) — recompute discards
+    # emitted tokens, so TTFT is the time to the first token of the
+    # attempt that actually completed, matching what a streaming client
+    # replaying the stream would see.
     first_token: float = dataclasses.field(compare=False, default=-1.0)
+    # lifecycle stamps on the clock ``arrival`` uses (-1.0 = not yet):
+    # when the gateway handed the request to the tick server, and when
+    # ``StepPlanner.build`` first put a chunk of it into a plan. Neither
+    # resets on requeue; queue wait is ``scheduled - delivered``.
+    delivered: float = dataclasses.field(compare=False, default=-1.0)
+    scheduled: float = dataclasses.field(compare=False, default=-1.0)
     tokens_out: int = dataclasses.field(compare=False, default=0)
     # multi-tenant serving (ISSUE 10): the submitting tenant ("" = the
     # single-tenant planes, which never read it) and the priority tier.

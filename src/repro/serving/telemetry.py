@@ -1,57 +1,64 @@
-"""Serving-wide telemetry plane: tracing, step timers, metrics, roofline.
+"""Serving-wide telemetry plane: spans, a trace ring, metrics.
 
-Four cooperating pieces, all optional and all zero-cost when detached
-(every instrumentation site in the serving stack guards on
-``telemetry is None`` — no context managers, no clock reads, no extra
-dispatches on the disabled path; ``tests/test_telemetry.py`` proves
-disabled runs bit-identical):
+The serving plane's spans sit on the profiler's clock. Every
+instrumentation site opens one helper, :func:`span`, under a fixed
+``dstack.*`` name:
 
-* :class:`TraceRecorder` — a bounded ring buffer of structured spans and
-  instants, exported as Chrome-trace-event JSON (``to_chrome_trace`` /
-  ``save``) loadable in Perfetto or ``chrome://tracing``. One track per
-  engine (``engine/<model>@<chips>ch``), one per model queue
-  (``queue/<model>``), one per tick server (``tick/<model>``). The
-  deterministic projection ``key_sequence()`` (everything except
-  wall-clock ``ts``/``dur``) is what the seeded-chaos determinism test
-  compares.
-* :class:`StepTimers` — ``perf_counter`` wall-clock samples around
-  block-until-ready dispatches, keyed ``(model, chips, kind, bucket)``.
-  Feeds :func:`roofline_report`, which joins measured dispatch latency
-  against ``core/latency_model`` predictions and flags deviations (on
-  CPU hosts the flags are the point: the rooflines model a TPU).
-* :class:`MetricsRegistry` — labelled counters/gauges/histograms with
-  Prometheus text exposition (``render``) and a matching parser for
-  tests/CI. The ``export_*`` bridges register the existing ad-hoc
-  counters (engine ``stats``, ``RequestQueue`` per-cause terminals,
-  ``FaultInjector.injected``, pool occupancy/Jain) so
-  ``PoolMetrics``/``ModelPoolMetrics`` become snapshot views over one
-  coherent exposition.
-* :class:`Telemetry` — the umbrella object the serving layers hold. The
-  engine calls :meth:`Telemetry.dispatch_done` after each of its ≤3
-  dispatches; planners/pools emit lifecycle instants
-  (:meth:`request_event`); the event loop emits arrivals.
+* ``dstack.gateway.deliver`` / ``dstack.gateway.pump`` — the wall-clock
+  gateway handing one arrival to the tick server; moving emitted tokens
+  into client streams;
+* ``dstack.tick`` (carries ``tick``) — one executed tick, holding
+  ``dstack.planner.build``, ``dstack.engine.execute`` and
+  ``dstack.planner.observe``;
+* ``dstack.engine.grow`` / ``.prefill`` / ``.chunk`` / ``.decode`` /
+  ``.spec`` — the host packing and enqueue of each dispatch of a plan
+  (never a wait: device time is read from the profiler's device trace,
+  under the step's ``dstack_*`` jit name);
+* ``dstack.engine.host_sync`` (carries ``site``) — every blocking
+  device-to-host read, counted in ``EngineStats.host_syncs``.
+
+Each span is a ``jax.profiler.TraceAnnotation``: with no profiler session
+it is a no-op, so capturing a profile (``jax.profiler.start_trace`` /
+``trace``) is the on/off switch. When a :class:`Telemetry` holding a
+:class:`TraceRecorder` is attached, the same spans also land in that
+ring, which exports Chrome-trace-event JSON (``to_chrome_trace`` /
+``save``) loadable in Perfetto or ``chrome://tracing``: one track per
+engine (``engine/<model>@<chips>ch``), model queue (``queue/<model>``)
+and tick server (``tick/<model>``: ticks, planner and gateway spans). The
+deterministic projection ``key_sequence()`` (everything except wall-clock
+``ts``/``dur``) is what the seeded-chaos determinism test compares.
+Nothing here blocks on the device; attaching or detaching a ``Telemetry``
+changes no stream, dispatch or compile (``tests/test_telemetry.py``).
+
+Beside the spans, cheap scalars are always on: the engine's counters
+(``EngineStats``, ``host_syncs`` and ``executables_built`` among them),
+and each ``Request``'s ``delivered``, ``scheduled`` and ``first_token``
+stamps on the gateway's clock. :class:`MetricsRegistry` — labelled
+counters/gauges/histograms with Prometheus text exposition (``render``)
+and a matching parser — registers those counters through the
+``export_*`` bridges (engine ``stats``, ``RequestQueue`` per-cause
+terminals, ``FaultInjector.injected``, pool occupancy/Jain).
 
 Request timelines (queued → admitted → chunk ticks → first token →
-terminal) are reconstructible from the instants via
-:func:`request_timelines`; TTFT/TBT themselves are recorded always-on in
-``RequestQueue`` (they are cheap scalars, not telemetry).
+terminal) are reconstructible from the ring's queue-track instants via
+:func:`request_timelines`.
 """
 from __future__ import annotations
 
 import collections
-import dataclasses
 import json
 import math
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "TraceRecorder", "StepTimers", "Telemetry", "MetricsRegistry",
+    "TraceRecorder", "Telemetry", "span", "MetricsRegistry",
     "Counter", "Gauge", "Histogram", "validate_chrome_trace",
-    "parse_prometheus", "roofline_report", "format_roofline",
-    "export_queue", "export_fault_injector", "export_engine_stats",
-    "export_pool_result", "request_timelines",
+    "parse_prometheus", "export_queue", "export_fault_injector",
+    "export_engine_stats", "export_pool_result", "request_timelines",
 ]
 
 
@@ -163,10 +170,15 @@ class TraceRecorder:
         """Deterministic projection: everything but wall-clock fields.
 
         Two seeded runs of the same workload must produce identical
-        key sequences even though ``ts``/``dur`` differ.
+        key sequences even though ``ts``/``dur`` differ. Compile events
+        (``cat="compile"``) are left out: whether a step builds a new
+        executable depends on what the process ran before, not on the
+        workload.
         """
         out = []
         for ev in self.events:
+            if ev.get("cat") == "compile":
+                continue
             args = tuple(sorted(ev.get("args", {}).items()))
             out.append((ev["track"], ev["ph"], ev["name"],
                         ev.get("cat", "serving"), args))
@@ -236,41 +248,48 @@ def validate_chrome_trace(obj: Any) -> int:
 
 
 # --------------------------------------------------------------------------
-# Wall-clock step timers
+# Spans: one helper, two sinks
 # --------------------------------------------------------------------------
 
-class StepTimers:
-    """Wall-clock dispatch samples keyed ``(model, chips, kind, bucket)``.
+class _RecordedSpan:
+    """A profiler annotation whose span also lands in a ``TraceRecorder``
+    ring, under the same name, on ``track``."""
 
-    ``kind`` is the dispatch family (``admission_prefill``,
-    ``chunk_prefill``, ``decode``, ``grow``); ``bucket`` is the jit
-    bucket the dispatch ran at (packed token bucket for prefills, batch
-    size for decode). These are the per-(model, allocation, bucket)
-    latency histograms the roofline report joins against predictions.
-    """
+    __slots__ = ("_ann", "_rec", "_track", "_name", "_cat", "_args", "_t0")
 
-    def __init__(self):
-        self.samples: Dict[Tuple[str, int, str, int], List[float]] = {}
+    def __init__(self, ann, rec: TraceRecorder, track: str, name: str,
+                 cat: str, args: Dict[str, Any]):
+        self._ann, self._rec, self._track = ann, rec, track
+        self._name, self._cat, self._args = name, cat, args
+        self._t0 = 0.0
 
-    def record(self, model: str, chips: int, kind: str, bucket: int,
-               seconds: float) -> None:
-        self.samples.setdefault((str(model), int(chips), str(kind),
-                                 int(bucket)), []).append(float(seconds))
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
 
-    @property
-    def total_samples(self) -> int:
-        return sum(len(v) for v in self.samples.values())
+    def __exit__(self, *exc):
+        t0 = self._t0
+        self._rec.complete(self._track, self._name, t0,
+                           time.perf_counter() - t0, cat=self._cat,
+                           **self._args)
+        return self._ann.__exit__(*exc)
 
-    def summary(self) -> List[Dict[str, Any]]:
-        from repro.serving.metrics import percentile
-        rows = []
-        for (model, chips, kind, bucket), xs in sorted(self.samples.items()):
-            rows.append({"model": model, "chips": chips, "kind": kind,
-                         "bucket": bucket, "n": len(xs),
-                         "p50_s": percentile(xs, 0.5),
-                         "p99_s": percentile(xs, 0.99),
-                         "mean_s": sum(xs) / len(xs)})
-        return rows
+
+def span(tel: Optional["Telemetry"], track: str, name: str,
+         cat: str = "serving", **args):
+    """The one span every instrumentation site of the serving plane opens.
+
+    Always a ``jax.profiler.TraceAnnotation`` named ``name`` (a fixed
+    ``dstack.*`` name) carrying ``args``: with no profiler session live it
+    is a no-op TraceMe, so the profiler session is the on/off switch. When
+    ``tel`` holds a ``TraceRecorder`` the span is also recorded there, on
+    ``track``. Keep ``args`` to values the site already has: they are
+    evaluated whether or not anything records them."""
+    ann = TraceAnnotation(name, **args)
+    if tel is None or tel.trace is None:
+        return ann
+    return _RecordedSpan(ann, tel.trace, track, name, cat, args)
 
 
 # --------------------------------------------------------------------------
@@ -278,18 +297,17 @@ class StepTimers:
 # --------------------------------------------------------------------------
 
 class Telemetry:
-    """What the serving layers hold: a trace (optional) plus timers.
+    """What the serving layers hold: the ``TraceRecorder`` ring sink
+    (optional) for their spans and instants.
 
     Attach with ``EnginePool.attach_telemetry`` /
     ``InferenceEngine.attach_telemetry`` / ``StepPlanner.telemetry``.
-    When ``trace`` is None only the wall-clock timers run (used by
-    ``bench_pool`` for the roofline report without trace export).
+    The profiler sink needs no attaching: every span is a
+    ``TraceAnnotation`` whether or not a ``Telemetry`` is held.
     """
 
-    def __init__(self, trace: Optional[TraceRecorder] = None,
-                 timers: Optional[StepTimers] = None):
+    def __init__(self, trace: Optional[TraceRecorder] = None):
         self.trace = trace
-        self.timers = timers if timers is not None else StepTimers()
 
     # -- track names -------------------------------------------------------
     @staticmethod
@@ -302,31 +320,10 @@ class Telemetry:
         return f"queue/{model}"
 
     # -- emission helpers --------------------------------------------------
-    def t0(self) -> float:
-        return time.perf_counter()
-
-    def dispatch_done(self, engine, kind: str, bucket: int, t0: float,
-                      sync=None, **args) -> None:
-        """Close a timed dispatch: block until device-done, record.
-
-        ``sync`` is whatever the dispatch produced (arrays / pytrees);
-        blocking on it makes the ``perf_counter`` window cover device
-        execution, not just Python-side enqueue. Only ever called when
-        telemetry is attached, so the disabled path never blocks.
-        """
-        if sync is not None:
-            import jax
-            jax.block_until_ready(sync)
-        dt = time.perf_counter() - t0
-        chips = getattr(engine, "alloc_chips", 0) or 0
-        self.timers.record(engine.cfg.name, chips, kind, bucket, dt)
+    def instant(self, track: str, name: str, cat: str = "serving",
+                **args) -> None:
         if self.trace is not None:
-            self.trace.complete(self.engine_track(engine), kind, t0, dt,
-                                cat="dispatch", bucket=int(bucket), **args)
-
-    def instant(self, track: str, name: str, **args) -> None:
-        if self.trace is not None:
-            self.trace.instant(track, name, **args)
+            self.trace.instant(track, name, cat=cat, **args)
 
     def request_event(self, model: str, name: str, **args) -> None:
         """Lifecycle instant on the model's queue track."""
@@ -555,7 +552,9 @@ def export_engine_stats(reg: MetricsRegistry, stats, model: str,
             ("draft_tokens", "dstack_draft_tokens_total"),
             ("accepted_tokens", "dstack_accepted_tokens_total"),
             ("spec_rounds", "dstack_spec_rounds_total"),
-            ("rollbacks", "dstack_spec_rollbacks_total")):
+            ("rollbacks", "dstack_spec_rollbacks_total"),
+            ("host_syncs", "dstack_host_syncs_total"),
+            ("executables_built", "dstack_executables_built_total")):
         reg.counter(name).inc(getattr(stats, field, 0), **labels)
 
 
@@ -614,89 +613,6 @@ def export_pool_result(reg: MetricsRegistry, result,
             tbt.observe(v, model=name)
     if injector is not None:
         export_fault_injector(reg, injector)
-
-
-# --------------------------------------------------------------------------
-# Roofline validation
-# --------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class RooflineRow:
-    model: str
-    chips: int
-    kind: str
-    bucket: int
-    n: int
-    measured_p50_s: float
-    predicted_s: Optional[float]
-    ratio: Optional[float]
-    flagged: bool
-
-    def as_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
-
-
-def roofline_report(timers: StepTimers, profiles: Dict[str, Any],
-                    tol: float = 4.0) -> List[RooflineRow]:
-    """Join measured dispatch wall-clock against latency-model predictions.
-
-    ``profiles`` maps model name → ``ModelProfile`` (as on
-    ``EnginePool.profiles``). Decode dispatches are predicted by a
-    decode-mode ``LatencyModel`` at ``batch=bucket``; prefill dispatches
-    by a prefill-mode model at ``seq=bucket`` (the packed token bucket),
-    batch 1. ``grow`` dispatches (block-table updates) have no analytic
-    model and get no prediction. A row is flagged when measured/predicted
-    falls outside ``[1/tol, tol]`` — on CPU hosts essentially every row
-    flags, which is exactly the signal: the rooflines model a TPU, the
-    host is not one.
-    """
-    from repro.core.latency_model import LatencyModel
-    from repro.serving.metrics import percentile
-
-    lm_cache: Dict[Tuple, Any] = {}
-    rows: List[RooflineRow] = []
-    for (model, chips, kind, bucket), xs in sorted(timers.samples.items()):
-        prof = profiles.get(model)
-        predicted = None
-        if prof is not None and chips >= 1:
-            if kind == "decode":
-                key = (model, "decode")
-                lm = lm_cache.get(key)
-                if lm is None:
-                    lm = LatencyModel(prof.cfg, mode="decode", seq=1,
-                                      hw=prof.hw)
-                    lm_cache[key] = lm
-                predicted = lm.latency(chips, max(1, bucket))
-            elif kind in ("admission_prefill", "chunk_prefill"):
-                key = (model, "prefill", bucket)
-                lm = lm_cache.get(key)
-                if lm is None:
-                    lm = LatencyModel(prof.cfg, mode="prefill",
-                                      seq=max(1, bucket), hw=prof.hw)
-                    lm_cache[key] = lm
-                predicted = lm.latency(chips, 1)
-        p50 = percentile(xs, 0.5)
-        ratio = (p50 / predicted) if predicted else None
-        flagged = ratio is not None and not (1.0 / tol <= ratio <= tol)
-        rows.append(RooflineRow(model=model, chips=chips, kind=kind,
-                                bucket=int(bucket), n=len(xs),
-                                measured_p50_s=p50, predicted_s=predicted,
-                                ratio=ratio, flagged=flagged))
-    return rows
-
-
-def format_roofline(rows: Iterable[RooflineRow]) -> List[str]:
-    out = ["model         chips kind              bucket    n "
-           "measured_p50 predicted    ratio flag"]
-    for r in rows:
-        pred = f"{r.predicted_s * 1e6:9.1f}us" if r.predicted_s else \
-            "        --"
-        ratio = f"{r.ratio:8.1f}" if r.ratio is not None else "      --"
-        out.append(f"{r.model:<13} {r.chips:>5} {r.kind:<17} "
-                   f"{r.bucket:>6} {r.n:>4} "
-                   f"{r.measured_p50_s * 1e6:9.1f}us {pred} {ratio}"
-                   f" {'DEV' if r.flagged else 'ok'}")
-    return out
 
 
 # --------------------------------------------------------------------------

@@ -287,7 +287,12 @@ def test_spec_counters_surface_everywhere(target):
         eng.attach_telemetry(None)
         eng._draft.attach_telemetry(None)
     kinds = {ev["name"] for ev in tel.trace.events}
-    assert {"spec_draft", "spec_verify", "spec_round"} <= kinds
+    assert {"dstack.engine.spec", "spec_round"} <= kinds
+    # the round's two read-backs: the draft's proposals, the verify's
+    # per-token argmax
+    sites = {ev["args"].get("site") for ev in tel.trace.events
+             if ev["name"] == "dstack.engine.host_sync"}
+    assert {"spec_proposals", "spec_argmax"} <= sites
     rounds = [ev for ev in tel.trace.events if ev["name"] == "spec_round"]
     assert all("accepted" in ev["args"] and "drafted" in ev["args"]
                for ev in rounds)

@@ -1,13 +1,22 @@
-"""Serving telemetry plane (ISSUE 7): tracing, timers, metrics, roofline.
+"""Serving telemetry plane: spans on the profiler's clock, the trace ring,
+counters and request stamps, metrics.
 
 The load-bearing claims:
 
-* **Zero overhead, zero behavior change when disabled.** Serving with no
-  telemetry attached takes no clock reads and no extra dispatches; a
-  run with telemetry attached produces BIT-IDENTICAL streams, identical
-  dispatch counts, and compiles nothing new (the trace is a pure
-  observer). Disabled runs before and after an enabled run also match —
-  attaching/detaching leaves no residue.
+* **Zero behavior change.** A run with a ``TraceRecorder`` attached
+  produces BIT-IDENTICAL streams, identical dispatch counts, and
+  compiles nothing new (the trace is a pure observer; nothing blocks on
+  the device). Disabled runs before and after an enabled run also match
+  — attaching/detaching leaves no residue.
+* **The program's own spans on the profiler's clock.** Under
+  ``jax.profiler`` a served tick reads ``dstack.tick`` holding
+  ``dstack.planner.build``, ``dstack.engine.execute`` (its dispatch and
+  ``host_sync`` spans inside) and ``dstack.planner.observe``, beside the
+  named jitted steps (``PjitFunction(dstack_...)``).
+* **Counters and stamps.** ``host_syncs`` counts the blocking reads (one
+  on a decode-only tick); on the wall-clock gateway every request reads
+  ``arrival <= delivered <= scheduled <= first_token``, its first token
+  stamped after the tick that made it executed.
 * **Valid traces.** Every export is Chrome-trace-event JSON that passes
   ``validate_chrome_trace``: known phases, finite non-negative
   timestamps, spans nested-or-disjoint per track — i.e. loadable in
@@ -20,9 +29,11 @@ The load-bearing claims:
   ``parse_prometheus`` is lossless for counters, gauges, and histogram
   bucket/sum/count lines.
 """
+import glob
 import json
-import math
+import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,11 +41,13 @@ import pytest
 from repro.configs import get_config
 from repro.serving.engine import make_engine
 from repro.serving.faults import FaultInjector
-from repro.serving.plan import PlannerConfig, StepPlanner, serve_ticks
+from repro.serving.gateway import AsyncGateway
+from repro.serving.plan import (PlannerConfig, StepPlanner, TickServer,
+                                serve_ticks)
 from repro.serving.request import Request, RequestQueue
-from repro.serving.telemetry import (MetricsRegistry, StepTimers, Telemetry,
+from repro.serving.telemetry import (MetricsRegistry, Telemetry,
                                      TraceRecorder, parse_prometheus,
-                                     request_timelines, roofline_report,
+                                     request_timelines, span,
                                      validate_chrome_trace)
 
 CACHE_LEN = 32
@@ -114,8 +127,12 @@ def test_disabled_runs_bit_identical_and_tracing_pure_observer(engine):
         "tracing changed what was dispatched"
     assert eng.jit_cache_sizes() == jit_before, "tracing compiled something"
 
-    # the trace actually observed the run
-    assert tel.timers.total_samples > 0
+    # the trace actually observed the run: the program's spans, one
+    # host_sync per decode read-back at least
+    names = {ev["name"] for ev in tel.trace.events if ev["ph"] == "X"}
+    assert {"dstack.tick", "dstack.planner.build", "dstack.engine.execute",
+            "dstack.planner.observe", "dstack.engine.host_sync"} <= names
+    assert eng.stats.host_syncs >= eng.stats.decode_steps > 0
     obj = tel.trace.to_chrome_trace()
     n_spans = validate_chrome_trace(obj)
     assert n_spans > 0
@@ -126,8 +143,9 @@ def test_disabled_runs_bit_identical_and_tracing_pure_observer(engine):
     # per-dispatch sub-spans exist on the engine track, nested in execute
     kinds = {ev["name"] for ev in tel.trace.events
              if ev.get("cat") == "dispatch"}
-    assert "admission_prefill" in kinds and "decode" in kinds
-    assert any(ev["name"] == "execute" for ev in tel.trace.events)
+    assert "dstack.engine.prefill" in kinds and "dstack.engine.decode" in kinds
+    assert any(ev["name"] == "dstack.engine.execute"
+               for ev in tel.trace.events)
 
     # per-request timeline: queued -> admitted -> first_token -> complete
     tl = request_timelines(tel.trace)
@@ -259,34 +277,184 @@ def test_prometheus_roundtrip():
         reg.gauge("dstack_requests_total")
 
 
+
+
 # ---------------------------------------------------------------------------
-# roofline report: joins measured samples against the latency model
+# the span helper: a profiler annotation always, a ring span when attached
 # ---------------------------------------------------------------------------
-def test_roofline_report_flags_deviations():
-    from repro.core.profiles import build_profile
-    prof = build_profile(MODEL, request_rate=2000)
-    timers = StepTimers()
-    lm_pred = None
-    # decode at batch=4 on 2 chips: plant samples AT the prediction (ok)
-    from repro.core.latency_model import LatencyModel
-    lm = LatencyModel(prof.cfg, mode="decode", seq=1, hw=prof.hw)
-    lm_pred = lm.latency(2, 4)
-    for _ in range(5):
-        timers.record(MODEL, 2, "decode", 4, lm_pred)
-    # prefill at bucket 64, wildly slow (flagged)
-    for _ in range(5):
-        timers.record(MODEL, 2, "admission_prefill", 64, 10.0)
-    # grow: no analytic model -> no prediction, never flagged
-    timers.record(MODEL, 2, "grow", 1, 0.001)
-    # unknown model -> no prediction
-    timers.record("nope", 2, "decode", 4, 0.001)
-    rows = {(r.kind, r.model): r
-            for r in roofline_report(timers, {MODEL: prof}, tol=4.0)}
-    ok = rows[("decode", MODEL)]
-    assert ok.predicted_s == pytest.approx(lm_pred)
-    assert ok.ratio == pytest.approx(1.0) and not ok.flagged
-    dev = rows[("admission_prefill", MODEL)]
-    assert dev.predicted_s and dev.ratio > 4.0 and dev.flagged
-    assert rows[("grow", MODEL)].predicted_s is None
-    assert not rows[("grow", MODEL)].flagged
-    assert rows[("decode", "nope")].predicted_s is None
+def test_span_helper_has_two_sinks():
+    with span(None, "tick/m", "dstack.tick", tick=3) as s:
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+    tel = Telemetry()                           # no ring: profiler only
+    with span(tel, "tick/m", "dstack.tick", tick=3) as s:
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+    tel = Telemetry(trace=TraceRecorder())
+    with span(tel, "tick/m", "dstack.tick", tick=3):
+        with span(tel, "tick/m", "dstack.planner.build"):
+            pass
+    evs = [(e["track"], e["name"], e["args"]) for e in tel.trace.events]
+    # children close first
+    assert evs == [("tick/m", "dstack.planner.build", {}),
+                   ("tick/m", "dstack.tick", {"tick": 3})]
+    assert validate_chrome_trace(tel.trace.to_chrome_trace()) == 2
+
+
+def _host_events(profile_dir):
+    """(name, start ns, duration ns) of every event on the profile's host
+    planes."""
+    (path,) = glob.glob(os.path.join(profile_dir, "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, int(ev.start_ns), int(ev.duration_ns))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def _inside(child, parent):
+    return (parent[1] <= child[1]
+            and child[1] + child[2] <= parent[1] + parent[2])
+
+
+def test_profiler_sees_the_program_spans_and_named_steps(engine, tmp_path):
+    """A served run under ``jax.profiler`` on the CPU: each executed tick
+    is a ``dstack.tick`` holding build, execute (with its dispatch spans
+    and host syncs) and observe, and the served decode step dispatches as
+    ``PjitFunction(dstack_slot_step)`` on the host."""
+    cfg, eng = engine
+    reqs, prompts = _workload(cfg, seed=5, n=3)
+    _serve(cfg, eng, reqs, prompts)              # warm every shape first
+    for r in reqs:
+        r.state = "pending"
+    with jax.profiler.trace(str(tmp_path)):
+        _, _, srv = _serve(cfg, eng, reqs, prompts)
+    evs = _host_events(str(tmp_path))
+    by = {}
+    for e in evs:
+        by.setdefault(e[0], []).append(e)
+    ticks = by["dstack.tick"]
+    assert len(ticks) == srv.ticks
+    for name in ("dstack.planner.build", "dstack.engine.execute",
+                 "dstack.planner.observe"):
+        assert len(by[name]) == srv.ticks
+        assert all(any(_inside(e, t) for t in ticks) for e in by[name])
+    execs = by["dstack.engine.execute"]
+    for name in ("dstack.engine.decode", "dstack.engine.prefill",
+                 "dstack.engine.host_sync"):
+        assert by[name] and all(any(_inside(e, x) for x in execs)
+                                for e in by[name]), name
+    # the dispatch of the step (the profiler nests two events per call)
+    steps = by["PjitFunction(dstack_slot_step)"]
+    decodes = by["dstack.engine.decode"]
+    assert all(any(_inside(e, d) for e in steps) for d in decodes)
+    assert all(any(_inside(e, d) for d in decodes) for e in steps)
+
+
+def test_host_syncs_count_one_read_on_a_decode_only_tick(engine):
+    cfg, eng = engine
+    reqs, prompts = _workload(cfg, seed=31, n=2)
+    eng.release_all_slots()
+    eng.reset_stats()
+    planner = StepPlanner(eng, RequestQueue(cfg.name, slo=1e9),
+                          PlannerConfig(chunk_tokens=64, gen_len=4))
+    host = {r.rid: {"tokens": np.asarray(prompts[r.rid]["tokens"])}
+            for r in reqs}
+    srv = TickServer(planner, lambda r: host[r.rid])
+    for r in reqs:
+        srv.deliver(r)
+    build = planner.build
+    plans = []
+
+    def w_build(now):
+        plans.append(build(now))
+        return plans[-1]
+    planner.build = w_build
+    deltas = []
+    while planner.busy():
+        before = eng.stats.host_syncs
+        srv.fire(float(srv.ticks))
+        deltas.append(eng.stats.host_syncs - before)
+    assert eng.free_pages == eng.total_pages
+    decode_only = [d for p, d in zip(plans, deltas)
+                   if p.decodes and not (p.admissions or p.grows
+                                         or p.forced or p.spec)]
+    assert decode_only and set(decode_only) == {1}
+    # the admission tick read back its prefilled token count, and
+    # decoded nothing
+    assert plans[0].admissions and not plans[0].decodes
+    assert deltas[0] == 1
+
+
+def test_wall_clock_gateway_stamps_each_request_in_order(engine):
+    """``arrival <= delivered <= scheduled <= first_token`` on the
+    gateway's clock, and the first token is stamped after the execute of
+    the tick that admitted the request returned."""
+    cfg, eng = engine
+    reqs, prompts = _workload(cfg, seed=41, n=5)
+    for i, r in enumerate(reqs):
+        r.arrival = 0.01 * i
+    eng.release_all_slots()
+    eng.reset_stats()
+    planner = StepPlanner(eng, RequestQueue(cfg.name, slo=1e9),
+                          PlannerConfig(chunk_tokens=3, lazy=True,
+                                        gen_len=4))
+    gw = AsyncGateway(planner, wall_clock=True)
+    execute = eng.execute
+    admitted_after = {}
+
+    def w_execute(plan):
+        res = execute(plan)
+        t = gw._elapsed()
+        for rid in res.admitted:
+            admitted_after.setdefault(rid, t)
+        return res
+    eng.execute = w_execute
+    try:
+        streams = gw.serve_trace(reqs, prompts)
+    finally:
+        del eng.execute
+    assert all(st.state == "completed" for st in streams.values())
+    for r in reqs:
+        assert r.arrival <= r.delivered <= r.scheduled <= r.first_token, r
+        assert r.first_token >= admitted_after[r.rid]
+    assert eng.free_pages == eng.total_pages
+
+
+def test_every_jitted_step_has_a_dstack_name(engine):
+    cfg, eng = engine
+    reqs, prompts = _workload(cfg, seed=3, n=2)
+    _serve(cfg, eng, reqs, prompts)
+    eng.prefill({"tokens": jnp.ones((1, 4), jnp.int32)})
+    eng.generate({"tokens": jnp.ones((1, 4), jnp.int32)}, 2)
+    fns = [v for v in vars(eng).values()
+           if callable(v) and hasattr(v, "lower")]
+    for d in (eng._prefill_jit, eng._gen_jit, eng._packed_prefill_jit,
+              eng._chunk_prefill_jit, eng._slot_step_jit):
+        assert d, "a jit dictionary stayed empty"
+        fns.extend(d.values())
+    names = {f.__name__ for f in fns}
+    assert len(fns) > 10 and all(n.startswith("dstack_") for n in names), \
+        names
+
+
+def test_executables_built_counts_jit_dictionary_misses():
+    cfg = get_config(MODEL).reduced()
+    eng = make_engine(cfg, cache_len=CACHE_LEN).init_slots(
+        2, paged=True, page_size=PAGE)
+    tel = Telemetry(trace=TraceRecorder())
+    eng.attach_telemetry(tel)
+    prompt = {"tokens": np.ones((1, 5), np.int32)}
+    slots = eng.insert_many([prompt], n_tokens=[2])
+    eng.step(slots)
+    built = eng.stats.executables_built
+    assert built == 2          # one packed prefill, one slot step
+    steps = [ev["args"]["step"] for ev in tel.trace.events
+             if ev["name"] == "executable_built"]
+    assert steps == ["dstack_prefill_packed", "dstack_slot_step"]
+    for s in slots:
+        eng.free(s)
+    slots = eng.insert_many([prompt], n_tokens=[2])     # warm: no miss
+    eng.step(slots)
+    assert eng.stats.executables_built == built
+    # build events never enter the deterministic projection
+    assert all(k[2] != "executable_built"
+               for k in tel.trace.key_sequence())
