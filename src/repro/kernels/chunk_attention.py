@@ -9,17 +9,20 @@ the new chunk is computed, dropping continuation cost from O(L²/chunk)
 to O(chunk)) and speculative-decoding verification (the k draft tokens
 are the chunk; their logits score the draft in one dispatch).
 
-TPU design mirrors the paged decode kernel: the pool is head-major
-``(KV, P, page_size, D)`` and the wrapper lays the chunk's queries and K/V
-out head-major too, so every block's last two dims are a (rows, D) tile
-Mosaic can lower. Grid ``(segments, kv_heads, max_pages + 1)`` with the
-page dim innermost. Iterations ``j < max_pages`` stream history pages
-HBM→VMEM with the same block-table index map — past-history lookups clamp
-onto the last live page so revisit-elision never DMAs dead pages; the
-final iteration ``j == max_pages`` attends the chunk's own rows under a
-local causal mask and finalizes the online softmax. Block tables, history
-lengths, and segment lengths all ride in via scalar prefetch so the index
-maps can page.
+TPU design mirrors the paged decode kernel: the pool is head-major and
+stacked over layers, ``(L, KV, P, page_size, D)``, read at a layer index
+that the history index map applies (no slice of the pool leaves the
+kernel: a scan over the pool as ``xs`` made two whole-layer copies per
+layer of each continuation chunk), and the wrapper lays the chunk's
+queries and K/V out head-major too, so every block's last two dims are a
+(rows, D) tile Mosaic can lower. Grid ``(segments, kv_heads, max_pages +
+1)`` with the page dim innermost. Iterations ``j < max_pages`` stream
+history pages HBM→VMEM with the same block-table index map —
+past-history lookups clamp onto the last live page so revisit-elision
+never DMAs dead pages; the final iteration ``j == max_pages`` attends the
+chunk's own rows under a local causal mask and finalizes the online
+softmax. Block tables, history lengths, segment lengths and the layer
+index all ride in via scalar prefetch so the index maps can page.
 
 Rows r >= the segment's length are unspecified (padding); callers slice
 the valid region. History length 0 (a fresh sequence) is fine — the
@@ -38,8 +41,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _chunk_kernel(tbl_ref, hist_ref, slen_ref, q_ref, kh_ref, vh_ref,
-                  kc_ref, vc_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _chunk_kernel(tbl_ref, hist_ref, slen_ref, layer_ref, q_ref, kh_ref,
+                  vh_ref, kc_ref, vc_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   scale: float, page_size: int, n_pages: int, rep: int,
                   chunk: int, window: int):
     si = pl.program_id(0)
@@ -102,19 +105,23 @@ def _chunk_kernel(tbl_ref, hist_ref, slen_ref, q_ref, kh_ref, vh_ref,
 
 
 def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
-                          block_tables, hist_lens, seg_lens, *,
+                          block_tables, hist_lens, seg_lens, layer=None, *,
                           window: int = 0, interpret: bool = False):
     """q/k_chunk/v_chunk: (S, R, H|KV, D) — R chunk rows per segment;
-    pages: (KV, P, page_size, D) head-major pools; block_tables:
-    (S, max_pages) int32; hist_lens/seg_lens: (S,) int32.
+    pages: (L, KV, P, page_size, D) stacked head-major pools read at
+    ``layer`` (an int32 scalar), or one layer's (KV, P, page_size, D)
+    pools with ``layer`` None; block_tables: (S, max_pages) int32;
+    hist_lens/seg_lens: (S,) int32.
 
     Chunk row r of segment s sits at absolute position hist_lens[s] + r;
     it attends paged history [0, hist_lens[s]) plus chunk rows [0, r]
     with r < seg_lens[s]. Returns (S, R, H, D); rows r >= seg_lens[s]
     are unspecified padding. Table entries at or past the last live
     history page are never dereferenced (the index map clamps)."""
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     s_, r, h, d = q.shape
-    kvh, _, page_size, _ = k_pages.shape
+    _, kvh, _, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     rep = h // kvh
     # flatten queries to (R*rep, D) rows per kv head: row p*rep + u is
@@ -126,29 +133,30 @@ def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
     hist_lens = jnp.asarray(hist_lens, jnp.int32).reshape(-1)
     seg_lens = jnp.asarray(seg_lens, jnp.int32).reshape(-1)
     block_tables = jnp.asarray(block_tables, jnp.int32).reshape(-1)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     kernel = functools.partial(
         _chunk_kernel, scale=1.0 / np.sqrt(d), page_size=page_size,
         n_pages=max_pages, rep=rep, chunk=r, window=window)
 
-    def hist_map(s_i, g, j, tbl_ref, hist_ref, slen_ref):
+    def hist_map(s_i, g, j, tbl_ref, hist_ref, slen_ref, layer_ref):
         # clamp past-history logical pages onto the last live one so the
         # repeated block index elides the DMA (dead pages stay in HBM);
         # the j == max_pages iteration reuses the last page harmlessly
         last = jnp.maximum(
             (hist_ref[s_i] + page_size - 1) // page_size, 1) - 1
         page = tbl_ref[s_i * max_pages + jnp.minimum(j, last)]
-        return (g, page, 0, 0)
+        return (layer_ref[0], g, page, 0, 0)
 
-    def chunk_map(s_i, g, j, tbl_ref, hist_ref, slen_ref):
+    def chunk_map(s_i, g, j, tbl_ref, hist_ref, slen_ref, layer_ref):
         return (s_i, g, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(s_, kvh, max_pages + 1),
         in_specs=[
             pl.BlockSpec((None, None, r * rep, d), chunk_map),
-            pl.BlockSpec((None, None, page_size, d), hist_map),
-            pl.BlockSpec((None, None, page_size, d), hist_map),
+            pl.BlockSpec((None, None, None, page_size, d), hist_map),
+            pl.BlockSpec((None, None, None, page_size, d), hist_map),
             pl.BlockSpec((None, None, r, d), chunk_map),
             pl.BlockSpec((None, None, r, d), chunk_map),
         ],
@@ -166,6 +174,7 @@ def paged_chunk_attention(q, k_pages, v_pages, k_chunk, v_chunk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, hist_lens, seg_lens, qg, k_pages, v_pages, kc, vc)
+    )(block_tables, hist_lens, seg_lens, layer, qg, k_pages, v_pages, kc,
+      vc)
     out = out.reshape(s_, kvh, r, rep, d)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_, r, h, d)

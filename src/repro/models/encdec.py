@@ -281,7 +281,7 @@ def decode_step(params, cfg, token, cache):
     cross K/V streams through the scan as xs (no double-buffering)."""
     dtype = jnp.dtype(cfg.dtype)
     pos = jnp.broadcast_to(jnp.asarray(cache["pos"], jnp.int32), token.shape)
-    update, attend, _ = L.decode_index(pos, cache, "k")
+    update_attend = L.decode_index(pos, cache, "k")
     x = (L.embed_tokens(params["embed"], token, dtype)
          + params["dec_pos"][pos].astype(dtype))
     positions = pos
@@ -293,11 +293,7 @@ def decode_step(params, cfg, token, cache):
         h = L.apply_norm(lp["ln1"], h0, cfg.norm)
         q, k, v = L.attn_qkv(lp["self_attn"], cfg, h[:, None, :], positions[:, None])
         q = L.constrain_q_decode(cfg, q[:, 0])
-        kc = jax.lax.dynamic_slice_in_dim(kfull, idx, 1, axis=0)[0]
-        vc = jax.lax.dynamic_slice_in_dim(vfull, idx, 1, axis=0)[0]
-        kc = update(kc, k)
-        vc = update(vc, v)
-        attn = attend(q, kc, vc)
+        attn, kfull, vfull = update_attend(q, k, v, kfull, vfull, idx)
         x1 = h0 + L.attn_out(lp["self_attn"], h0.dtype, attn)
 
         h2 = L.apply_norm(lp["ln2"], x1, cfg.norm)
@@ -308,8 +304,6 @@ def decode_step(params, cfg, token, cache):
 
         h3 = L.apply_norm(lp["ln3"], x2, cfg.norm)
         x3 = x2 + L.apply_mlp(lp["mlp"], h3)
-        kfull = jax.lax.dynamic_update_slice_in_dim(kfull, kc[None], idx, axis=0)
-        vfull = jax.lax.dynamic_update_slice_in_dim(vfull, vc[None], idx, axis=0)
         return (x3, kfull, vfull), None
 
     (x, ks, vs), _ = jax.lax.scan(

@@ -241,7 +241,7 @@ def decode_step(params, cfg, token, cache):
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed_tokens(params["embed"], token, dtype)
     pos = jnp.broadcast_to(jnp.asarray(cache["pos"], jnp.int32), token.shape)
-    update, attend, _ = L.decode_index(pos, cache, "attn_k")
+    update_attend = L.decode_index(pos, cache, "attn_k")
     positions = pos
     sp = params["shared_attn"]
     na = n_attn_blocks(cfg)
@@ -257,16 +257,10 @@ def decode_step(params, cfg, token, cache):
             hh = L.apply_norm(sp["ln1"], h_, cfg.norm)
             q, k, v = L.attn_qkv(sp["attn"], cfg, hh[:, None, :], positions[:, None])
             q = L.constrain_q_decode(cfg, q[:, 0])
-            kj = jax.lax.dynamic_slice_in_dim(kc_, j, 1, axis=0)[0]
-            vj = jax.lax.dynamic_slice_in_dim(vc_, j, 1, axis=0)[0]
-            kj = update(kj, k)
-            vj = update(vj, v)
-            attn = attend(q, kj, vj)
+            attn, kc_, vc_ = update_attend(q, k, v, kc_, vc_, j)
             h2 = h_ + L.attn_out(sp["attn"], h_.dtype, attn)
             hh2 = L.apply_norm(sp["ln2"], h2, cfg.norm)
             h2 = h2 + L.apply_mlp(sp["mlp"], hh2)
-            kc_ = jax.lax.dynamic_update_slice_in_dim(kc_, kj[None], j, axis=0)
-            vc_ = jax.lax.dynamic_update_slice_in_dim(vc_, vj[None], j, axis=0)
             return h2, kc_, vc_
 
         h, kc, vc = jax.lax.cond(

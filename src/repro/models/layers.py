@@ -341,16 +341,26 @@ def cache_row_update(buf, new, slot):
     return buf.at[bidx, slot].set(new[:, 0])
 
 
-def paged_cache_update(buf, new, pages, slots):
-    """Write ``new`` (B, 1, KV, D) into a head-major paged pool ``buf``
-    (KV, P, page_size, D) at per-row physical page ``pages`` (B,) and
-    in-page offset ``slots`` (B,).
+def paged_cache_update(buf, new, layer, pages, slots):
+    """Write ``new`` (B, 1, KV, D) into layer ``layer`` of a stacked
+    head-major paged pool ``buf`` (L, KV, P, page_size, D) at per-row
+    physical page ``pages`` (B,) and in-page offset ``slots`` (B,).
+
+    The write is a scatter of B x KV separate (D,) rows, each at
+    (layer, head, page, slot): the scatter's window is the head dim alone,
+    which is already minor in the pool's row-major layout, so the compiler
+    keeps the pool where it is and scatters in place. Do not write it as
+    ``buf[layer].at[:, pages, slots]``: a (KV, D) window makes the compiler
+    copy the layer out into a layout that puts both dims minor and back
+    again (four whole-layer copies per layer and pool in a decode step).
 
     Live rows own disjoint pages so the scatter rows never collide; vacant
     rows all target the reserved null page 0 at offset 0 — duplicate
     indices there are harmless because the null page is never read (see
     ``repro.serving.kv_cache``)."""
-    return buf.at[:, pages, slots].set(new[:, 0].swapaxes(0, 1))
+    heads = jnp.arange(buf.shape[1])[None, :]
+    return buf.at[layer, heads, pages[:, None], slots[:, None]].set(
+        new[:, 0])
 
 
 def gather_pages(pool, block_tables):
@@ -362,6 +372,12 @@ def gather_pages(pool, block_tables):
     rows = pool[:, block_tables]               # (KV, B, max_pages, ps, D)
     return rows.transpose(1, 2, 3, 0, 4).reshape(
         b, max_pages * page_size, kvh, d)
+
+
+def _layer_pool(pool, layer):
+    """One layer of a stacked (L, KV, P, page_size, D) pool, or ``pool``
+    itself where ``layer`` is None (a single layer's pool)."""
+    return pool if layer is None else pool[layer]
 
 
 def _paged_backend(page_size: int) -> str:
@@ -400,13 +416,16 @@ def _masked_decode_attention(q, k_cache, v_cache, lengths):
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_tables, valid_len):
+def paged_decode_attention(q, k_pages, v_pages, block_tables, valid_len,
+                           layer=None):
     """Single-token attention over a block-table paged KV cache.
 
-    q: (B, H, D); k_pages/v_pages: (KV, P, page_size, D) shared physical
-    head-major pools; block_tables: (B, max_pages) int32 page ids;
-    valid_len: scalar or per-sequence (B,) int32 lengths. Rows with length
-    0 (vacant slots, table rows parked on the null page) return zeros.
+    q: (B, H, D); k_pages/v_pages: (L, KV, P, page_size, D) shared physical
+    head-major pools, stacked over layers and read at ``layer`` (or one
+    layer's (KV, P, page_size, D) pools with ``layer`` None);
+    block_tables: (B, max_pages) int32 page ids; valid_len: scalar or
+    per-sequence (B,) int32 lengths. Rows with length 0 (vacant slots,
+    table rows parked on the null page) return zeros.
 
     On real TPUs this dispatches to the paged Pallas kernel
     (repro.kernels.paged_attention): the block table rides in via scalar
@@ -418,15 +437,15 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, valid_len):
     b = q.shape[0]
     lengths = jnp.broadcast_to(
         jnp.asarray(valid_len, jnp.int32).reshape(-1), (b,))
-    backend = _paged_backend(k_pages.shape[2])
+    backend = _paged_backend(k_pages.shape[-2])
     if backend != "jnp":
         from repro.kernels.paged_attention import \
             paged_decode_attention as _pallas
-        return _pallas(q, k_pages, v_pages, block_tables, lengths,
+        return _pallas(q, k_pages, v_pages, block_tables, lengths, layer,
                        interpret=backend == "interpret")
     # jnp gather fallback: pages -> logical (B, C, KV, D) view
-    kc = gather_pages(k_pages, block_tables)
-    vc = gather_pages(v_pages, block_tables)
+    kc = gather_pages(_layer_pool(k_pages, layer), block_tables)
+    vc = gather_pages(_layer_pool(v_pages, layer), block_tables)
     return _masked_decode_attention(q, kc, vc, lengths)
 
 
@@ -452,7 +471,7 @@ def _masked_chunk_attention(q_rows, k_cache, v_cache, lengths):
 
 
 def paged_chunk_attention(q_rows, k_pages, v_pages, k_rows, v_rows,
-                          block_tables, hist_lens, seg_lens):
+                          block_tables, hist_lens, seg_lens, layer=None):
     """Incremental chunk attention: R new tokens per segment attend the
     K/V their sequence already wrote into the shared page pool plus the
     chunk's own K/V causally — the continuation/verification sibling of
@@ -460,9 +479,11 @@ def paged_chunk_attention(q_rows, k_pages, v_pages, k_rows, v_rows,
 
     q_rows/k_rows/v_rows: (S, R, H|KV, D) per-segment chunk rows (row r
     of segment s sits at absolute position hist_lens[s] + r);
-    k_pages/v_pages: (KV, P, page_size, D); block_tables: (S, max_pages)
-    int32; hist_lens/seg_lens: (S,) int32. Rows r >= seg_lens[s] are
-    padding (zeros in, garbage out — callers discard them).
+    k_pages/v_pages: (L, KV, P, page_size, D) stacked pools read at
+    ``layer`` (or one layer's (KV, P, page_size, D) with ``layer`` None);
+    block_tables: (S, max_pages) int32; hist_lens/seg_lens: (S,) int32.
+    Rows r >= seg_lens[s] are padding (zeros in, garbage out — callers
+    discard them).
 
     On real TPUs this dispatches to the chunked paged Pallas kernel
     (repro.kernels.chunk_attention); the fallback gathers each segment's
@@ -474,15 +495,15 @@ def paged_chunk_attention(q_rows, k_pages, v_pages, k_rows, v_rows,
         jnp.asarray(hist_lens, jnp.int32).reshape(-1), (s,))
     slen = jnp.broadcast_to(
         jnp.asarray(seg_lens, jnp.int32).reshape(-1), (s,))
-    backend = _paged_backend(k_pages.shape[2])
+    backend = _paged_backend(k_pages.shape[-2])
     if backend != "jnp":
         from repro.kernels.chunk_attention import \
             paged_chunk_attention as _pallas
         return _pallas(q_rows, k_pages, v_pages, k_rows, v_rows,
-                       block_tables, hist, slen,
+                       block_tables, hist, slen, layer,
                        interpret=backend == "interpret")
-    kc = gather_pages(k_pages, block_tables)
-    vc = gather_pages(v_pages, block_tables)
+    kc = gather_pages(_layer_pool(k_pages, layer), block_tables)
+    vc = gather_pages(_layer_pool(v_pages, layer), block_tables)
     pos = hist[:, None] + jnp.arange(r_len, dtype=jnp.int32)[None, :]
     sidx = jnp.arange(s)[:, None]
     kc = kc.at[sidx, pos].set(k_rows, mode="drop")
@@ -500,10 +521,18 @@ def decode_index(pos, cache, key):
     pytree property: ``block_tables`` present = paged).
 
     pos: (B,) int32 current positions; ``key``: the K leaf the layout is
-    read from. Returns ``(update, attend, valid)``: ``update(buf, new)``
-    writes the step's (B, 1, ...) entries at each row's coordinates;
-    ``attend(q, kc, vc, window=0)`` runs decode attention against the
-    updated buffer; ``valid`` is the (B,) lengths vector."""
+    read from. Returns ``update_attend(q, k, v, kbuf, vbuf, layer,
+    window=0)``, which writes the step's (B, 1, KV, D) entries ``k``/``v``
+    at each row's coordinates in layer ``layer`` of the stacked K/V leaves
+    ``kbuf``/``vbuf``, runs decode attention of ``q`` against that layer,
+    and returns ``(out, kbuf, vbuf)``.
+
+    A paged pool (L, KV, P, page_size, D) is never sliced: the write is a
+    scatter of (D,) rows at (layer, head, page, slot)
+    (``paged_cache_update``) and the kernel picks the layer in its index
+    maps, so the pool keeps one row-major layout and the step copies none
+    of it. A ring (L, B, C, KV, D) slices its layer out, writes and reads
+    the slice, and updates it back into the stack."""
     if "block_tables" in cache:
         tables = cache["block_tables"]
         page_size = cache[key].shape[3]
@@ -516,10 +545,7 @@ def decode_index(pos, cache, key):
         slot = pos % page_size
         valid = jnp.minimum(pos + 1, tables.shape[1] * page_size)
 
-        def update(buf, new):
-            return paged_cache_update(buf, new, page, slot)
-
-        def attend(q, kc, vc, window: int = 0):
+        def update_attend(q, k, v, kbuf, vbuf, layer, window: int = 0):
             if window:
                 # a paged slot retains FULL history (pages never evict),
                 # so windowed attention needs page-level masking that is
@@ -527,21 +553,30 @@ def decode_index(pos, cache, key):
                 # ring slots, whose overwrite IS the window. Loud > wrong.
                 raise NotImplementedError(
                     "sliding-window attention over a paged cache")
-            return paged_decode_attention(q, kc, vc, tables, valid)
+            kbuf = paged_cache_update(kbuf, k, layer, page, slot)
+            vbuf = paged_cache_update(vbuf, v, layer, page, slot)
+            out = paged_decode_attention(q, kbuf, vbuf, tables, valid, layer)
+            return out, kbuf, vbuf
 
-        return update, attend, valid
+        return update_attend
 
     cache_len = cache[key].shape[2]
     slot = (pos % cache_len) if cache_len > 0 else jnp.zeros_like(pos)
     valid = jnp.minimum(pos + 1, cache_len)
 
-    def update(buf, new):
-        return cache_row_update(buf, new, slot)
+    def update_attend(q, k, v, kbuf, vbuf, layer, window: int = 0):
+        kc = cache_row_update(
+            jax.lax.dynamic_index_in_dim(kbuf, layer, keepdims=False), k,
+            slot)
+        vc = cache_row_update(
+            jax.lax.dynamic_index_in_dim(vbuf, layer, keepdims=False), v,
+            slot)
+        out = decode_attention(q, kc, vc, valid, window=window)
+        kbuf = jax.lax.dynamic_update_index_in_dim(kbuf, kc, layer, 0)
+        vbuf = jax.lax.dynamic_update_index_in_dim(vbuf, vc, layer, 0)
+        return out, kbuf, vbuf
 
-    def attend(q, kc, vc, window: int = 0):
-        return decode_attention(q, kc, vc, valid, window=window)
-
-    return update, attend, valid
+    return update_attend
 
 
 def carry_cache_meta(out, cache):

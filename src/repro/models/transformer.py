@@ -272,15 +272,19 @@ def prefill_chunk(params, cfg, packed, cache, max_seg_len: int):
     n_rows = cache["block_tables"].shape[0]
     tables = cache["block_tables"][jnp.clip(seg_slots, 0, n_rows - 1)]
 
+    # the pools stay out of the scan's xs (each layer's slice would be a
+    # whole-layer copy): every layer reads the stacked pools at its index
+    kp, vp = cache["k"], cache["v"]
+
     def body(carry, xs):
-        lp, kp, vp = xs
+        lp, idx = xs
         h = L.apply_norm(lp["ln1"], carry, cfg.norm)
         q, k, v = L.attn_qkv(lp["attn"], cfg, h, positions)
         qr = L.segments_to_rows(q[0], seg_starts, seg_lens, max_seg_len)
         kr = L.segments_to_rows(k[0], seg_starts, seg_lens, max_seg_len)
         vr = L.segments_to_rows(v[0], seg_starts, seg_lens, max_seg_len)
         ar = L.paged_chunk_attention(qr, kp, vp, kr, vr, tables, hist,
-                                     seg_lens)
+                                     seg_lens, idx)
         attn = L.rows_to_segments(ar, seg_ids, local)[None]
         x1 = carry + L.attn_out(lp["attn"], carry.dtype, attn)
         h2 = L.apply_norm(lp["ln2"], x1, cfg.norm)
@@ -291,7 +295,7 @@ def prefill_chunk(params, cfg, packed, cache, max_seg_len: int):
         return x1 + y, (k[0], v[0])
 
     x, (ks, vs) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+        body, x, (params["layers"], jnp.arange(cfg.num_layers)))
     xl = L.apply_norm(params["final_norm"], x[0], cfg.norm)
     logits_all = L.unembed(params["embed"], xl, cfg)           # (T, V)
     tok_argmax = jnp.argmax(logits_all, -1).astype(jnp.int32)
@@ -309,23 +313,26 @@ def decode_step(params, cfg, token, cache) -> Tuple[jax.Array, dict]:
     mixed-length (ragged) batch never pays for the longest row and vacant
     continuous-batching slots cost nothing but the row's lane.
 
-    The cache is threaded through the layer scan as CARRY and updated with
-    dynamic_update_slice at the layer index — a scan-over-(xs -> ys) cache
-    double-buffers (measured +2x cache HBM on deepseek decode_32k); the
-    carried buffer updates in place and aliases with the donated input.
+    The cache is threaded through the layer scan as CARRY and written at
+    the layer index (``layers.decode_index``) — a scan-over-(xs -> ys)
+    cache double-buffers (measured +2x cache HBM on deepseek decode_32k);
+    the carried buffer updates in place and aliases with the donated
+    input. A ring cache slices its layer out and updates it back.
 
     A cache built by ``init_paged_cache`` (it carries ``block_tables``; a
     static pytree property, so this is a trace-time branch, not a runtime
-    one) stores K/V in the shared page pool instead: each row writes its
-    new entry at (block_tables[b, pos // page_size], pos % page_size) and
-    attends through ``paged_decode_attention``. Same scan-carry structure,
-    same per-sequence raggedness — only the storage indexing differs.
+    one) stores K/V in the shared page pool (layers, KV, P, page_size, D)
+    instead, and the step never slices it: each row's new entry is
+    scattered as KV rows of (D,) at (layer, head, block_tables[b, pos //
+    page_size], pos % page_size), and ``paged_decode_attention`` reads
+    the stacked pool at the layer index. Same scan-carry structure, same
+    per-sequence raggedness — only the storage indexing differs.
     """
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed_tokens(params["embed"], token, dtype)          # (B, d)
     pos = jnp.broadcast_to(jnp.asarray(cache["pos"], jnp.int32), token.shape)
     positions = pos
-    update, attend, _ = L.decode_index(pos, cache, "k")
+    update_attend = L.decode_index(pos, cache, "k")
 
     def body(carry, xs):
         h0, kfull, vfull = carry
@@ -333,19 +340,14 @@ def decode_step(params, cfg, token, cache) -> Tuple[jax.Array, dict]:
         h = L.apply_norm(lp["ln1"], h0, cfg.norm)
         q, k, v = L.attn_qkv(lp["attn"], cfg, h[:, None, :], positions[:, None])
         q = L.constrain_q_decode(cfg, q[:, 0])                 # (B, H, hd)
-        kc = jax.lax.dynamic_slice_in_dim(kfull, idx, 1, axis=0)[0]
-        vc = jax.lax.dynamic_slice_in_dim(vfull, idx, 1, axis=0)[0]
-        kc = update(kc, k)
-        vc = update(vc, v)
-        attn = attend(q, kc, vc, window=cfg.sliding_window)
+        attn, kfull, vfull = update_attend(q, k, v, kfull, vfull, idx,
+                                           window=cfg.sliding_window)
         x1 = h0 + L.attn_out(lp["attn"], h0.dtype, attn)
         h2 = L.apply_norm(lp["ln2"], x1, cfg.norm)
         if cfg.num_experts:
             y, _ = apply_moe(lp["moe"], cfg, h2)
         else:
             y = L.apply_mlp(lp["mlp"], h2)
-        kfull = jax.lax.dynamic_update_slice_in_dim(kfull, kc[None], idx, axis=0)
-        vfull = jax.lax.dynamic_update_slice_in_dim(vfull, vc[None], idx, axis=0)
         return (x1 + y, kfull, vfull), None
 
     (x, ks, vs), _ = jax.lax.scan(

@@ -2075,12 +2075,14 @@ def _make_write_segments(paged_keys):
     """Build the packed-insert scatter: per-TOKEN leaves (the family's
     ``PAGED_KEYS`` — packed (layers, T, ...) order) scatter each token at
     its (dest0, dest1) coordinate, which is (physical page, offset) on a
-    paged cache and (slot row, column) on a ring; every other leaf is
-    per-SEGMENT and takes a batched row write at the slot ids. Padding
-    tokens land on the null page (paged, dead by convention) or out of
-    bounds (ring, dropped by scatter semantics); padding segments carry
-    slot id n_slots (out of bounds, dropped). One static-shape executable
-    per packed-token bucket — the batch's segment count never retraces."""
+    paged cache — as (D,) rows at (layer, head, page, offset), so the
+    pool is written in place — and (slot row, column) on a ring; every
+    other leaf is per-SEGMENT and takes a batched row write at the slot
+    ids. Padding tokens land on the null page (paged, dead by convention)
+    or out of bounds (ring, dropped by scatter semantics); padding
+    segments carry slot id n_slots (out of bounds, dropped). One
+    static-shape executable per packed-token bucket — the batch's segment
+    count never retraces."""
     paged_keys = frozenset(paged_keys)
 
     def dstack_write_segments(cache, last_tok, pcache, logits, dest0, dest1,
@@ -2093,8 +2095,15 @@ def _make_write_segments(paged_keys):
             elif key in paged_keys:
                 o = pcache[key].astype(b_leaf.dtype)   # (layers, T, KV, D)
                 if paged:       # head-major pool (layers, KV, P, ps, D)
-                    out[key] = b_leaf.at[:, :, dest0, dest1].set(
-                        o.swapaxes(1, 2))
+                    # one (D,) row per (layer, token, head): a (D,)
+                    # window keeps the pool in place, where a (KV, D)
+                    # one (``b_leaf.at[:, :, dest0, dest1]``) made the
+                    # compiler copy the whole pool out and back
+                    n_l, _, n_h, _ = o.shape
+                    out[key] = b_leaf.at[
+                        jnp.arange(n_l)[:, None, None],
+                        jnp.arange(n_h)[None, None, :],
+                        dest0[None, :, None], dest1[None, :, None]].set(o)
                 else:           # ring (layers, slots, C, KV, D)
                     out[key] = b_leaf.at[:, dest0, dest1].set(o)
             else:

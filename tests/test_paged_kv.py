@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels import ref
+from repro.kernels.chunk_attention import paged_chunk_attention
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.models import layers as L
 from repro.serving.engine import SamplingParams, make_engine
@@ -279,6 +280,87 @@ def test_paged_null_page_rows_return_zero():
     out = np.asarray(L.paged_decode_attention(q, kp, vp, tables, lv))
     assert (out[0] == 0).all() and (out[2] == 0).all()
     assert np.abs(out[1]).sum() > 0
+
+
+N_LAYERS = 3
+
+
+def _stacked_pool(shape, rng):
+    """A random (N_LAYERS,) + ``shape`` pool: every layer differs."""
+    return jnp.asarray(rng.standard_normal((N_LAYERS,) + tuple(shape)),
+                       jnp.float32)
+
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+@pytest.mark.parametrize("b,h,kv,d,ps,maxp,lengths", PAGED_CASES)
+def test_paged_kernel_stacked_pool_matches_layer(b, h, kv, d, ps, maxp,
+                                                 lengths, layer):
+    """The kernel reading a stacked (L, KV, P, ps, D) pool at ``layer``
+    returns exactly what it returns on that layer's pool alone."""
+    q, kp, _, tables, _, _ = _paged_setup(b, h, kv, d, ps, maxp, lengths)
+    rng = np.random.default_rng(layer)
+    ks, vs = _stacked_pool(kp.shape, rng), _stacked_pool(kp.shape, rng)
+    lv = jnp.asarray(lengths, jnp.int32)
+    out = paged_decode_attention(q, ks, vs, tables, lv, jnp.int32(layer),
+                                 interpret=True)
+    want = paged_decode_attention(q, ks[layer], vs[layer], tables, lv,
+                                  interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+STACKED_CHUNK_CASES = [
+    # (s, r, h, kv, d, page_size, max_pages, hists, slens)
+    (2, 8, 4, 2, 32, 8, 3, (5, 16), (8, 3)),     # mid-page + aligned
+    (3, 4, 8, 8, 32, 16, 2, (0, 13, 30), (4, 4, 2)),  # fresh seq, MHA
+]
+
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+@pytest.mark.parametrize("s,r,h,kv,d,ps,mp,hists,slens", STACKED_CHUNK_CASES)
+def test_chunk_kernel_stacked_pool_matches_layer(s, r, h, kv, d, ps, mp,
+                                                 hists, slens, layer):
+    rng = np.random.default_rng(layer)
+    n_pages = s * mp + 1
+    q, kc, vc = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                 for shape in ((s, r, h, d), (s, r, kv, d), (s, r, kv, d)))
+    ks = _stacked_pool((kv, n_pages, ps, d), rng)
+    vs = _stacked_pool((kv, n_pages, ps, d), rng)
+    tables = jnp.asarray((rng.permutation(n_pages - 1) + 1)[:s * mp]
+                         .reshape(s, mp), jnp.int32)
+    hist = jnp.asarray(hists, jnp.int32)
+    slen = jnp.asarray(slens, jnp.int32)
+    out = paged_chunk_attention(q, ks, vs, kc, vc, tables, hist, slen,
+                                jnp.int32(layer), interpret=True)
+    want = paged_chunk_attention(q, ks[layer], vs[layer], kc, vc, tables,
+                                 hist, slen, interpret=True)
+    for i in range(s):     # rows past a segment's length are padding
+        n = slens[i]
+        np.testing.assert_array_equal(np.asarray(out)[i, :n],
+                                      np.asarray(want)[i, :n])
+
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_paged_cache_update_writes_rows_in_place(layer):
+    """The row scatter writes each row's (KV, D) entry at (layer, head,
+    page, slot) and leaves every other layer and page bit-identical;
+    vacant rows land on the null page only."""
+    kv, n_pages, ps, d = 4, 9, 8, 16
+    rng = np.random.default_rng(layer)
+    buf = _stacked_pool((kv, n_pages, ps, d), rng)
+    new = jnp.asarray(rng.standard_normal((5, 1, kv, d)), jnp.float32)
+    pages = np.array([3, 7, NULL_PAGE, 1, NULL_PAGE], np.int32)
+    slots = np.array([0, 5, 0, 7, 0], np.int32)
+    out = np.asarray(L.paged_cache_update(buf, new, jnp.int32(layer),
+                                          jnp.asarray(pages),
+                                          jnp.asarray(slots)))
+    want = np.asarray(buf).copy()
+    for row in (0, 1, 3):                      # the live rows
+        want[layer, :, pages[row], slots[row]] = np.asarray(new)[row, 0]
+    live = np.ones(n_pages, bool)
+    live[NULL_PAGE] = False
+    np.testing.assert_array_equal(out[:, :, live], want[:, :, live])
+    others = [i for i in range(N_LAYERS) if i != layer]
+    np.testing.assert_array_equal(out[others], np.asarray(buf)[others])
 
 
 # ------------------------------------- paged vs ring engine parity (4 fams)

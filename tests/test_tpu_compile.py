@@ -10,13 +10,20 @@ shapes alone, so those refusals fail here instead of on the chip.
 Widths: qwen2-0.5b (H=14, KV=2, D=64), olmo-1b (H=KV=16, D=128) and the
 mamba2-1.3b SSD (64 heads, P=64, N=128, chunk 128), all in bf16 as served.
 
+The serving steps over the paged K/V pool are compiled whole as well, to
+count what the compiler does around the kernels: no instruction of the
+decode step, the segment write or the chunk prefill may yield a buffer of
+the pool's shape, or of one layer's, other than by passing it through.
+
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and under a
 multi-worker pytest run every worker imports this file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +37,8 @@ from repro.kernels.flash_attention import (flash_attention,
                                            segment_flash_attention)
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models.registry import build_model
+from repro.serving import engine
 
 BF16 = jnp.bfloat16
 I32 = jnp.int32
@@ -143,3 +152,98 @@ def test_ssd_scan_compiles(one_chip, batch, n_chunks):
         ((batch * heads, n_chunks, cl), f32),
         ((batch, n_chunks, cl, n), f32), ((batch, n_chunks, cl, n), f32),
         ((batch * heads, n, p), f32)))
+
+
+# instructions that pass a buffer through without making one
+_PASS_THROUGH = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def _made_buffers(text, layer):
+    """Instructions of an optimized HLO module, outside the bodies of its
+    fusions, that make a bf16 buffer of one or more layers of a pool whose
+    layer is ``layer`` (its shape ends in ``layer``'s dims) and do not
+    just pass one through."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    comp, made = None, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+            continue
+        inst = re.match(r"^\s+(?:ROOT )?%([\w.\-]+) = bf16\[([\d,]*)\]\S* "
+                        r"([\w\-]+)\(", line)
+        if not inst or comp in fused or inst.group(3) in _PASS_THROUGH:
+            continue
+        dims = tuple(int(x) for x in inst.group(2).split(",") if x)
+        if dims[-len(layer):] == tuple(layer):
+            made.append(f"{comp}: {inst.group(1)} {inst.group(3)}")
+    return made
+
+
+def test_paged_steps_never_copy_the_pool(one_chip, monkeypatch):
+    """The engine's decode step (donating the cache, as the engine does),
+    its segment write and the chunk prefill over olmo-1b's paged pool: the
+    pool keeps one layout, written by row scatters in place and read by
+    the kernels at a layer index, so nothing pool-shaped is made; the
+    decode step holds one kernel call (once per layer, in its loop).
+
+    The pool is the olmo-1b cell's (256 pages of 128 tokens and the null
+    page) and two layers: a pool small enough for the chip's on-core
+    memory is moved there whole, which would be counted here but is not
+    what the serving size compiles to."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "default_backend", lambda: "pallas")
+    cfg = dataclasses.replace(get_config("olmo-1b"), num_layers=2)
+    api = build_model(cfg)
+    n_slots, page_size, max_pages, pages = 64, 128, 16, 257
+    t, segs = 256, 2
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: api.init(jax.random.PRNGKey(0), BF16)))
+    cache = on_chip(jax.eval_shape(
+        lambda: api.init_paged_cache(n_slots, pages, page_size, max_pages)))
+    kv, d = cfg.num_kv_heads, cfg.resolved_head_dim
+    layer = (kv, pages, page_size, d)
+    assert cache["k"].shape == (cfg.num_layers,) + layer
+
+    skip = frozenset(api.paged_keys) | {"block_tables"}
+
+    def dstack_slot_step(p, tok, c, active):
+        return engine._slot_decode_step(api, skip, p, tok, c, active)
+
+    decode = jax.jit(dstack_slot_step, donate_argnums=(2,)).lower(
+        params, arg((n_slots,)), cache, arg((n_slots,), jnp.bool_))
+    decode = decode.compile().as_text()
+
+    packed = {"k": arg((cfg.num_layers, t, kv, d), BF16),
+              "v": arg((cfg.num_layers, t, kv, d), BF16),
+              "pos": arg((segs,))}
+    write = jax.jit(engine._make_write_segments(api.paged_keys),
+                    donate_argnums=(0, 1)).lower(
+        cache, arg((n_slots,)), packed,
+        arg((segs, cfg.vocab_size), jnp.float32), arg((t,)), arg((t,)),
+        arg((segs,)), arg((segs, max_pages)))
+    write = write.compile().as_text()
+
+    chunk_in = {"tokens": arg((1, t)), "seg_ids": arg((t,)),
+                "seg_starts": arg((segs,)), "seg_lens": arg((segs,)),
+                "seg_slots": arg((segs,)), "hist_lens": arg((segs,))}
+
+    def dstack_prefill_chunk(p, pk, c):
+        return api.prefill_chunk(p, pk, c, t)
+
+    chunk = jax.jit(dstack_prefill_chunk).lower(params, chunk_in, cache)
+    chunk = chunk.compile().as_text()
+
+    for name, text in (("decode", decode), ("segment write", write),
+                       ("chunk prefill", chunk)):
+        assert _made_buffers(text, layer) == [], name
+    assert decode.count("tpu_custom_call") == 1
+    assert chunk.count("tpu_custom_call") == 1
